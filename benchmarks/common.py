@@ -1,10 +1,12 @@
 """Shared benchmark machinery.
 
-CPU-container methodology (DESIGN.md §8): the paper's absolute GPU numbers
-can't be reproduced here; what is validated is the *claims structure* —
-which system wins where, how execution time grows, where build time
-dominates — using wall-clock of compiled JAX on scaled dataset sizes. Every
-benchmark prints ``name,case,seconds,derived`` CSV rows and returns them.
+Where the numbers come from: on a TPU the kernels run compiled (backend
+``kernel``) and ``seconds`` is chip wall time; on the CPU they run through
+the pure-jnp ``ref`` oracles (or ``interpret``), which checks the *claims
+structure* — which system wins where, how work grows, where build time
+dominates — but says nothing about speed on the chip. The rows do not yet
+name the device they ran on (ROADMAP A1). Every benchmark prints
+``name,case,seconds,derived`` CSV rows and returns them.
 
 Timing: one warmup call (compile + engine build), then ``repeats`` timed
 runs, median reported. Engine *build* time is timed separately where the

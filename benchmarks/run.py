@@ -130,7 +130,11 @@ def main(argv=None):
                          "(machine-independent; default 1.5)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+
     from . import common, figures
+
+    compile_cache.enable()
 
     if args.json or args.check_regress:
         common.JSON_SINK = []

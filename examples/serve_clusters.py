@@ -53,6 +53,7 @@ from repro import serve
 from repro.core.dbscan import dbscan
 from repro.data import synth
 from repro.data.pipeline import point_stream
+from repro.launch import compile_cache
 
 EPS, MINPTS = 0.08, 16
 N_CORPUS, N_STREAM, CHUNK = 20_000, 4_000, 512
@@ -369,6 +370,7 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.kill_shard is not None and args.shards is None:
         ap.error("--kill-shard requires --shards")
+    compile_cache.enable()
     if args.shards is not None:
         sharded_demo(args)
     elif args.wal_dir is None:

@@ -291,33 +291,51 @@ def slab_payload_min(payload, starts, nblk, *, block_k: int,
     """Per-tile min of ``payload`` over the tile's live slab blocks.
 
     payload (n_cand,) int32 — sorted-layout plane (INT32_MAX padding);
-    returns (T,) int32. One block-granular reduce (reshape + min) plus a
-    static ``max_blocks`` gather loop — O(n_cand + T·max_blocks), far below
-    one sweep. Used by the frontier round driver's live-tile test
+    returns (T,) int32. One block-granular reduce (reshape + min), then a
+    sparse table of block-range minima (level k covers 2^k blocks) answers
+    each tile's ``[start, start + nblk)`` range with two overlapping
+    lookups — O(n_cand + n_blocks·log max_blocks + T), far below one sweep,
+    and a program whose size does not grow with ``max_blocks`` (one
+    unrolled gather per block compiles for minutes on the chip at 10⁶
+    points). Used by the frontier round driver's live-tile test
     (DESIGN.md §11).
     """
     nb_tot = payload.shape[0] // block_k
     blk_min = payload.reshape(nb_tot, block_k).min(axis=1)
-    starts_blk = (starts // block_k).astype(jnp.int32)
-    out = jnp.full(starts.shape, INT32_MAX, jnp.int32)
-    for j in range(max_blocks):
-        idx = jnp.clip(starts_blk + j, 0, nb_tot - 1)
-        out = jnp.where(j < nblk, jnp.minimum(out, blk_min[idx]), out)
-    return out
+    max_span = max(min(max_blocks, nb_tot), 1)
+    tab = [blk_min]
+    while (1 << len(tab)) <= max_span:
+        h = 1 << (len(tab) - 1)
+        prev = tab[-1]
+        tab.append(jnp.minimum(prev, jnp.concatenate(
+            [prev[h:], jnp.full((h,), INT32_MAX, prev.dtype)])))
+    tab = jnp.stack(tab)
+    lo = jnp.clip(starts // block_k, 0, nb_tot - 1).astype(jnp.int32)
+    span = jnp.clip(nblk, 1, max_span).astype(jnp.int32)
+    k = 31 - jax.lax.clz(span)
+    hi = jnp.clip(lo + span - (1 << k), 0, nb_tot - 1)
+    out = jnp.minimum(tab[k, lo], tab[k, hi])
+    return jnp.where(nblk > 0, out, INT32_MAX).astype(jnp.int32)
 
 
 def slab_touched(flags, starts, nblk, n: int, *, block_k: int):
     """Per-tile "any flagged point in my slab" — the dirty-block test.
 
-    flags (n,) bool in sorted layout; returns (T,) bool. One prefix sum
-    over the point plane, then an O(T) two-gather range count per tile's
-    contiguous slab ``[starts, starts + nblk·block_k)`` — no new data
-    structure, the CSR plan's slab bounds are the ranges (DESIGN.md §11).
+    flags (n,) bool in sorted layout; returns (T,) bool. Slabs are whole
+    blocks (``starts`` is a multiple of ``block_k``), so one any-reduce per
+    block and a prefix sum over the *blocks* give an O(T) two-gather range
+    count per tile's contiguous slab ``[starts, starts + nblk·block_k)`` —
+    no new data structure, the CSR plan's slab bounds are the ranges
+    (DESIGN.md §11). The prefix sum runs over n / block_k entries, not n:
+    a point-length one compiles for seconds on the chip.
     """
+    nb_tot = -(-n // block_k)
+    blk = jnp.zeros((nb_tot * block_k,), bool).at[:n].set(flags) \
+        .reshape(nb_tot, block_k).any(axis=1)
     cum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                           jnp.cumsum(flags.astype(jnp.int32))])
-    lo = jnp.clip(starts, 0, n)
-    hi = jnp.clip(starts + nblk * block_k, 0, n)
+                           jnp.cumsum(blk.astype(jnp.int32))])
+    lo = jnp.clip(starts // block_k, 0, nb_tot)
+    hi = jnp.clip(starts // block_k + nblk, 0, nb_tot)
     return cum[hi] > cum[lo]
 
 

@@ -37,9 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
@@ -124,7 +121,7 @@ def bvh_batch_sweep(q_planar, dlo_planar, dhi_planar, pt_planar, croot, nmin,
             jax.ShapeDtypeStruct((nb, f), jnp.int32),
             jax.ShapeDtypeStruct((1, f), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -42,9 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 INT_MAX = jnp.iinfo(jnp.int32).max
 INF = float("inf")  # plain float: jnp scalars would be captured consts
 
@@ -148,7 +145,7 @@ def cross_sweep(queries, cands_planar, croot, starts_blk, nblk, eps2, *,
             jax.ShapeDtypeStruct((nq, 1), jnp.int32),
             jax.ShapeDtypeStruct((nq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

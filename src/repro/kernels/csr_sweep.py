@@ -29,9 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
@@ -131,7 +128,7 @@ def csr_sweep_counts(queries, cands_planar, starts_blk, nblk, eps2, *,
         _kernel_counts,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((nq, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -192,7 +189,7 @@ def csr_sweep(queries, cands_planar, croot, starts_blk, nblk, eps2, *,
             jax.ShapeDtypeStruct((nq, 1), jnp.int32),
             jax.ShapeDtypeStruct((nq, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .csr_sweep import _CompilerParams, _hit_mask, _slab_block
+from .csr_sweep import _hit_mask, _slab_block
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 
@@ -125,7 +125,7 @@ def frontier_sweep(queries, cands_planar, croot, starts_blk, nblk, active,
         _kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((nq, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

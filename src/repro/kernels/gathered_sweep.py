@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
@@ -84,7 +81,7 @@ def gathered_sweep(queries, cands_planar, croot, eps2, *, block_b: int = 128,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

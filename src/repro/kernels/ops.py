@@ -36,11 +36,7 @@ def default_backend() -> str:
     env = os.environ.get("REPRO_KERNEL_BACKEND")
     if env:
         return env
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:  # pragma: no cover
-        platform = "cpu"
-    return "kernel" if platform == "tpu" else "ref"
+    return "kernel" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def _round_up(x: int, m: int) -> int:

@@ -31,9 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 INT_MAX = jnp.iinfo(jnp.int32).max
 BIG = jnp.float32(1e30)
 
@@ -97,7 +94,7 @@ def pairwise_sweep(queries, cands_planar, croot, eps2, *, block_q: int = 256,
             jax.ShapeDtypeStruct((nq, 1), jnp.int32),
             jax.ShapeDtypeStruct((nq, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,6 +17,7 @@ from ..core import labels as L
 from ..core import neighbors as nb
 from ..core.dbscan import dbscan
 from ..data import synth
+from . import compile_cache
 
 
 def main(argv=None):
@@ -32,6 +33,7 @@ def main(argv=None):
                     help="shard over all local devices (shard_map path)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     pts = synth.load(args.dataset, args.n, seed=args.seed)
     print(f"dataset={args.dataset} n={args.n} eps={args.eps} "

@@ -131,6 +131,58 @@ def test_distributed_dbscan_dense_empty():
     assert "OK" in out
 
 
+def test_sharded_tier_on_four_devices():
+    # one shard per device: the placed tier must answer assign and fold
+    # ingest exactly as the single-snapshot session does
+    out = run_sub("""
+    from repro import serve
+    from repro.core.dbscan import dbscan
+    from repro.data import synth
+
+    eps, minpts = 0.08, 8
+    pts = synth.load("taxi2d", 3000, seed=3)
+    head, tail = pts[:2400], pts[2400:]
+    tier = serve.ShardedTier.build(head, eps, minpts, n_shards=4,
+                                   max_delta_frac=np.inf)
+    sess = serve.ServeSession(serve.build_snapshot(head, eps, minpts),
+                              max_delta_frac=np.inf)
+
+    def placement():
+        return [tuple(d.id for d in s.snapshot.cands.devices())
+                for s in tier.sessions]
+
+    def same_answers(seed):
+        q = synth.load("taxi2d", 500, seed=seed, structure_seed=3)
+        a, b = sess.assign(q), tier.assign(q)
+        assert not b.partial
+        for f in ("labels", "counts", "dist"):
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+
+    assert sorted(placement()) == [(0,), (1,), (2,), (3,)], placement()
+    same_answers(11)
+    for i in range(0, len(tail), 150):
+        tier.ingest(tail[i:i + 150])
+        sess.ingest(tail[i:i + 150])
+    tier.compact(force=True)
+    sess.compact(force=True)
+    assert sorted(placement()) == [(0,), (1,), (2,), (3,)], placement()
+    ref = dbscan(pts, eps, minpts)
+    np.testing.assert_array_equal(np.asarray(sess.snapshot.labels),
+                                  np.asarray(ref.labels))
+    lab = np.full(len(pts), -2, np.int64)
+    for p in tier.parts:
+        loc = np.asarray(p.snapshot.labels)
+        lab[p.orig_index] = np.where(
+            loc >= 0, p.label_table.astype(np.int64)[np.maximum(loc, 0)]
+            if p.label_table.size else -1, -1)
+    np.testing.assert_array_equal(lab, np.asarray(ref.labels))
+    same_answers(12)
+    tier.close()
+    print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
 def test_checkpoint_atomic_roundtrip(tmp_path):
     from repro.distributed import checkpoint as ckpt
     import jax.numpy as jnp

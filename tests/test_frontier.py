@@ -112,6 +112,35 @@ def test_counts_only_stage1_matches_full_sweep():
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref))
 
 
+@pytest.mark.parametrize("max_blocks", [1, 2, 5, 8, 13])
+def test_slab_reductions_match_point_loop(max_blocks):
+    # the frontier's per-tile slab min and dirty test must equal a plain
+    # walk over each slab's points, including empty slabs (nblk = 0),
+    # slabs ending at the last block, and a ragged last block (n < n_cand)
+    bk, nb_tot, T = 4, 16, 64
+    n = nb_tot * bk - 3
+    rng = np.random.default_rng(max_blocks)
+    payload = rng.integers(0, 1000, nb_tot * bk).astype(np.int32)
+    payload[rng.random(payload.size) < 0.3] = INT_MAX
+    flags = rng.random(n) < 0.02
+    nblk = rng.integers(0, max_blocks + 1, T).astype(np.int32)
+    starts = (rng.integers(0, nb_tot - max_blocks + 1, T) * bk).astype(
+        np.int32)
+    starts[:2] = (nb_tot - max_blocks) * bk
+    nblk[:2] = max_blocks, 0
+    slabs = [slice(s, s + m * bk) for s, m in zip(starts, nblk)]
+    got = grid_mod.slab_payload_min(jnp.asarray(payload), jnp.asarray(starts),
+                                    jnp.asarray(nblk), block_k=bk,
+                                    max_blocks=max_blocks)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.array([payload[s].min(initial=INT_MAX) for s in slabs], np.int32))
+    got = grid_mod.slab_touched(jnp.asarray(flags), jnp.asarray(starts),
+                                jnp.asarray(nblk), n, block_k=bk)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.array([flags[s].any() for s in slabs]))
+
+
 def test_no_host_sync_in_dbscan():
     # regression for the hidden host sync: the device drivers must return
     # n_rounds as a device scalar (converting with int() inside dbscan()
