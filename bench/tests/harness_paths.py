@@ -1,0 +1,9 @@
+"""Put the checkout root (for the ``bench`` package) and ``src`` (for the
+program) on ``sys.path``; the benchmark's tests import this first."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
