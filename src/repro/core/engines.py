@@ -32,10 +32,7 @@ do through the fields of the :class:`Engine` it builds:
                        ``dbscan(hook_loop="frontier")`` re-sweep only the
                        tiles that can still produce a union;
   * ``meta``         — the engine's static plan (GridSpec / CSRGridSpec /
-                       WavefrontSpec), exposed for benchmarks and reuse;
-  * ``timings``      — build-time breakdown (paper §V-D): ``make_engine``
-                       always records ``build_s``; builders may add
-                       finer-grained phases.
+                       WavefrontSpec), exposed for benchmarks and reuse.
 
 Builders receive the normalized ``(points, eps)`` pair plus the standard
 keyword surface (``backend``, ``chunk``, ``dims``, ``spec``) and any
@@ -48,11 +45,12 @@ shapes, overflow-flag regrow) — see :func:`register_local_engine`.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .. import obs
 
 
 class FrontierPlan(NamedTuple):
@@ -90,7 +88,6 @@ class Engine(NamedTuple):
     #                                  (counts, minroot), all in sorted layout
     order: Any = None                # (n,) sorted position -> original index
     neighbors: Callable | None = None  # (state, k_max=) -> (idx, counts)
-    timings: dict | None = None      # build-time breakdown, seconds
     query: Callable | None = None    # cross-corpus queries (serving,
     #                                  DESIGN.md §10): (state, queries, nq,
     #                                  croot_sorted, slab=, block_q=) ->
@@ -179,9 +176,8 @@ def make_engine(points, eps: float, *, engine: str = "grid",
 
     The structure build (cell sort / grid hashing / BVH build + frontier
     calibration) happens here — this is the phase the paper's §V-D breaks
-    out as "BVH build time"; its wall-clock is recorded in
-    ``Engine.timings["build_s"]`` and benchmarks time ``make_engine``
-    separately from the sweeps for the same breakdown. ``spec`` lets callers
+    out as "BVH build time"; a trace marks it as the ``repro.engine.build``
+    span, apart from the sweeps. ``spec`` lets callers
     reuse a plan (GridSpec for ``grid-hash``, CSRGridSpec for ``grid``,
     WavefrontSpec for ``bvh``); a reused spec must come from the same
     dataset — builds raise if its capacities don't fit. ``chunk`` tiles the
@@ -191,11 +187,9 @@ def make_engine(points, eps: float, *, engine: str = "grid",
     forwarded to the builder.
     """
     entry = get_engine_spec(engine)
-    points = jnp.asarray(points, jnp.float32)
-    t0 = time.perf_counter()
-    eng = entry.build(points, float(eps), backend=backend, chunk=chunk,
-                      dims=dims, spec=spec, **extra)
-    jax.block_until_ready(eng.state)
-    timings = dict(eng.timings or {})
-    timings.setdefault("build_s", time.perf_counter() - t0)
-    return eng._replace(timings=timings)
+    with obs.span("engine.build", engine=engine, n=len(points)):
+        points = jnp.asarray(points, jnp.float32)
+        eng = entry.build(points, float(eps), backend=backend, chunk=chunk,
+                          dims=dims, spec=spec, **extra)
+        jax.block_until_ready(eng.state)
+    return eng

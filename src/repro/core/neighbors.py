@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..kernels import ops
 from . import engines
 from . import grid as grid_mod
@@ -422,13 +423,17 @@ def _build_brute(points, eps, *, backend=None, chunk=2048, dims=None,
 def _build_csr(points, eps, *, backend=None, chunk=2048, dims=None,
                spec=None):
     eps2 = float(eps) ** 2
-    pts_np = np.asarray(points)
-    if dims is None:
-        dims = infer_dims(pts_np)
-    if spec is None:
-        spec = grid_mod.plan_csr_grid(pts_np, float(eps), dims=dims)
-    g = build_csr_grid_jit(points, spec)
-    if bool(g.overflow):
+    with obs.span("engine.plan", n=len(points)) as sp:
+        pts_np = np.asarray(points)
+        if dims is None:
+            dims = infer_dims(pts_np)
+        sp.set_metadata(dims=dims)
+        if spec is None:
+            spec = grid_mod.plan_csr_grid(pts_np, float(eps), dims=dims)
+    with obs.span("engine.layout", slab=spec.slab):
+        g = build_csr_grid_jit(points, spec)
+        overflowed = bool(g.overflow)
+    if overflowed:
         raise ValueError(
             "CSR grid build overflowed the planned slab capacity "
             f"(slab={spec.slab}) — the spec was planned for different "

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import neighbors as nb
 from . import faults
 from .resilience import next_slab, validate_points
@@ -45,7 +46,6 @@ class AssignResult(NamedTuple):
     dist: np.ndarray     # (nq,) f32: distance to the nearest deciding core
     #                      point (+inf for noise) — attachment confidence
     bucket: int          # padded batch size served (telemetry)
-    seconds: float       # device wall-clock for this call
     staleness: int = 0   # delta points ingested but not visible to this
     #                      answer (the delta watermark; 0 = fully fresh)
     degraded: bool = False  # True when the serving session is running on
@@ -67,24 +67,30 @@ class AssignResult(NamedTuple):
 def assign(snapshot: ClusterSnapshot, queries, *,
            scheduler: BucketScheduler | None = None,
            block_q: int = 256, backend: str | None = None,
-           max_regrow: int = nb.MAX_SLAB_REGROW) -> AssignResult:
+           max_regrow: int = nb.MAX_SLAB_REGROW,
+           req: int | None = None) -> AssignResult:
     """Label ``queries`` (nq, 3) against the frozen ``snapshot``.
 
     Pass a shared ``scheduler`` from a serving loop to get bucketed shape
     reuse and latency/recompile telemetry across calls; without one an
     ephemeral scheduler still buckets (so one-off calls hit the same jit
-    cache keys a loop would).
+    cache keys a loop would). ``req`` is the request id the call's spans
+    carry (``repro.obs``); a fresh one when not given.
     """
     sched = scheduler or BucketScheduler(min_bucket=block_q)
-    q_np = validate_points(queries, name="queries")
-    q_pad, nq = sched.pad(q_np)
-    if q_pad.shape[0] % block_q:
-        raise ValueError(
-            f"bucket {q_pad.shape[0]} not a multiple of block_q={block_q}; "
-            "set the scheduler's min_bucket to a multiple of block_q")
+    req = obs.next_req() if req is None else req
+    with obs.span("serve.prepare", req=req) as sp:
+        q_np = validate_points(queries, name="queries")
+        q_pad, nq = sched.pad(q_np)
+        sp.set_metadata(nq=nq, bucket=q_pad.shape[0])
+        if q_pad.shape[0] % block_q:
+            raise ValueError(
+                f"bucket {q_pad.shape[0]} not a multiple of "
+                f"block_q={block_q}; set the scheduler's min_bucket to a "
+                "multiple of block_q")
+        q_dev = jnp.asarray(q_pad)
     spec = snapshot.spec
     eps2 = float(snapshot.eps) ** 2
-    q_dev = jnp.asarray(q_pad)
 
     slab = snapshot.slab
     t0 = time.perf_counter()
@@ -96,25 +102,30 @@ def assign(snapshot: ClusterSnapshot, queries, *,
         return (spec, q_pad.shape[0], s, block_q, backend)
 
     for attempt in range(max_regrow + 1):
-        fn = nb._csr_cross_query_fn(spec, eps2, backend, slab, block_q)
-        counts, minroot, mind2, overflow = fn(
-            snapshot.codes, snapshot.cands, snapshot.croot_sorted, q_dev,
-            jnp.int32(nq))
-        jax.block_until_ready(counts)
-        if not bool(overflow) and not faults.fire("serve.assign.overflow"):
+        with obs.span("serve.run", req=req, slab=slab, attempt=attempt):
+            fn = nb._csr_cross_query_fn(spec, eps2, backend, slab, block_q)
+            counts, minroot, mind2, overflow = fn(
+                snapshot.codes, snapshot.cands, snapshot.croot_sorted,
+                q_dev, jnp.int32(nq))
+            jax.block_until_ready(counts)
+            overflowed = bool(overflow)
+        if not overflowed and not faults.fire("serve.assign.overflow"):
             break
-        sched.note_trace(trace_key(slab))  # the overflowed attempt compiled
-        sched.note_regrow()
-        slab = next_slab(slab, spec.n_cand, attempt=attempt,
-                         max_regrow=max_regrow, what="cross-query")
-        snapshot.note_slab(slab)
-    seconds = time.perf_counter() - t0
-    sched.note_call(trace_key(slab), seconds)
+        with obs.span("serve.regrow", req=req) as sp:
+            # the overflowed attempt compiled
+            sched.note_trace(trace_key(slab))
+            sched.note_regrow()
+            slab = next_slab(slab, spec.n_cand, attempt=attempt,
+                             max_regrow=max_regrow, what="cross-query")
+            sp.set_metadata(slab=slab)
+            snapshot.note_slab(slab)
+    sched.note_call(trace_key(slab), time.perf_counter() - t0)
 
-    counts = np.asarray(counts)[:nq]
-    minroot = np.asarray(minroot)[:nq]
-    mind2 = np.asarray(mind2)[:nq]
-    labels = np.where(minroot != INT_MAX, minroot, -1).astype(np.int32)
-    return AssignResult(labels=labels, counts=counts,
-                        dist=np.sqrt(mind2, dtype=np.float32),
-                        bucket=q_pad.shape[0], seconds=seconds)
+    with obs.span("serve.finish", req=req):
+        counts = np.asarray(counts)[:nq]
+        minroot = np.asarray(minroot)[:nq]
+        mind2 = np.asarray(mind2)[:nq]
+        labels = np.where(minroot != INT_MAX, minroot, -1).astype(np.int32)
+        return AssignResult(labels=labels, counts=counts,
+                            dist=np.sqrt(mind2, dtype=np.float32),
+                            bucket=q_pad.shape[0])

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import neighbors as nb
 from ..core.dbscan import _hook_step
 from ..core.union_find import pointer_jump
@@ -294,18 +295,22 @@ class ServeSession:
         ``staleness`` (the delta watermark — how many ingested points this
         answer cannot see) and ``degraded`` (breaker holding compaction).
         Raises ``AdmissionError`` when the admission queue is full."""
-        q_np = validate_points(queries, name="queries")
-        ticket = self.admission.admit(len(q_np))
-        t0 = time.perf_counter()
-        try:
-            return self._assign_admitted(q_np)
-        finally:
-            self.admission.finish(ticket, time.perf_counter() - t0)
+        req = obs.next_req()
+        with obs.span("serve.assign", req=req) as sp:
+            q_np = validate_points(queries, name="queries")
+            sp.set_metadata(nq=len(q_np))
+            ticket = self.admission.admit(len(q_np))
+            t0 = time.perf_counter()
+            try:
+                return self._assign_admitted(q_np, req)
+            finally:
+                self.admission.finish(ticket, time.perf_counter() - t0)
 
-    def _assign_admitted(self, q_np: np.ndarray) -> AssignResult:
+    def _assign_admitted(self, q_np: np.ndarray,
+                         req: int | None = None) -> AssignResult:
         try:
             r = assign(self.snapshot, q_np, scheduler=self.scheduler,
-                       block_q=self.block_q, backend=self.backend)
+                       block_q=self.block_q, backend=self.backend, req=req)
         except CapacityError:
             # a structurally-exhausted regrow is a rebuild-path failure:
             # count it toward the breaker so a corrupt layout trips it

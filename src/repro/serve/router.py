@@ -366,7 +366,6 @@ class ShardedTier:
             self.admission.finish(ticket, time.perf_counter() - t0)
 
     def _assign_admitted(self, q_np: np.ndarray) -> AssignResult:
-        t0 = time.perf_counter()
         mask = self.map.window_shards(q_np)
         self.scheduler.note_route(mask.sum(axis=1))
         nq = len(q_np)
@@ -408,7 +407,7 @@ class ShardedTier:
         labels = np.where(merged != INT64_MAX, merged, -1).astype(np.int32)
         return AssignResult(
             labels=labels, counts=counts, dist=dist_m, bucket=bucket,
-            seconds=time.perf_counter() - t0, staleness=staleness,
+            staleness=staleness,
             degraded=self.degraded or partial, partial=partial,
             shards=shard_status)
 

@@ -77,7 +77,6 @@ def test_wavefront_capabilities():
     assert np.array_equal(np.sort(np.asarray(wave.order)), np.arange(300))
     assert stack.sweep_sorted is None
     assert wave.meta.capacity % wave.meta.tile == 0
-    assert "build_s" in wave.timings
     # terminate=False keeps the exact engine but drops the frontier plan
     # (its compaction *is* the termination bound)
     exact = nb.make_engine(pts, 0.08, engine="bvh", terminate=False)
