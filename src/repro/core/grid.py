@@ -29,6 +29,7 @@ O(n·27·C_max). See ``plan_csr_grid`` / ``build_csr_grid``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import warnings
 from typing import NamedTuple
@@ -216,32 +217,37 @@ def csr_cells(points: jnp.ndarray, side: float, origin: tuple, dims: int,
     return c
 
 
+def _csr_window_codes(cells, dims: int, bits: int):
+    """(W, m) int32 Morton codes of each cell's 9 (2-D) / 27 (3-D) window
+    cells, clipped to the real-cell range plus the one free index above it
+    (``2^bits - 2``, never occupied)."""
+    from ..kernels import ref as _kref
+    rng = (-1, 0, 1)
+    offs = jnp.asarray([(dx, dy, dz) for dx in rng for dy in rng
+                        for dz in (rng if dims == 3 else (0,))], jnp.int32)
+    nb = jnp.clip(cells[None, :, :] + offs[:, None, :], 0, (1 << bits) - 2)
+    w, m = nb.shape[:2]
+    return _kref.morton_encode_ref(nb.reshape(w * m, 3),
+                                   dims=dims).reshape(w, m)
+
+
 def _csr_window_bounds(sorted_codes, cells, dims: int, bits: int):
     """Per query cell: [lo, hi) positions in the code-sorted corpus covering
-    the occupied runs of all 9/27 window cells. Empty window cells are
-    excluded (their searchsorted insertion point would needlessly widen the
-    slab).
+    the occupied runs of all 9/27 window cells, by binary search. Empty
+    window cells are excluded (their searchsorted insertion point would
+    needlessly widen the slab).
 
-    ``cells`` need not come from the corpus itself: the self-join build
-    passes the corpus's own sorted cells, while cross-corpus queries
-    (DESIGN.md §10) pass *fresh* query cells bisected against the frozen
+    For queries that are *not* the corpus: cross-corpus queries (DESIGN.md
+    §10) pass fresh query cells bisected against the frozen
     ``sorted_codes`` — the returned bounds have ``cells``'s length, not the
-    corpus's.
+    corpus's. With m ≪ n queries, W·m small gathers beat any pass over the
+    corpus; the self-join (m = n) uses :func:`_csr_self_bounds`.
     """
     n = sorted_codes.shape[0]
     m = cells.shape[0]
-    from ..kernels import ref as _kref
-    rng = (-1, 0, 1)
-    offs = [(dx, dy, dz) for dx in rng for dy in rng
-            for dz in (rng if dims == 3 else (0,))]
     lo = jnp.full((m,), n, jnp.int32)
     hi = jnp.zeros((m,), jnp.int32)
-    cell_cap = (1 << bits) - 2
-    for off in offs:
-        nb = jnp.clip(cells + jnp.asarray(off, jnp.int32), 0, cell_cap)
-        if dims == 2:
-            nb = nb.at[:, 2].set(0)
-        code = _kref.morton_encode_ref(nb, dims=dims)
+    for code in _csr_window_codes(cells, dims, bits):
         left = jnp.searchsorted(sorted_codes, code, side="left").astype(
             jnp.int32)
         right = jnp.searchsorted(sorted_codes, code, side="right").astype(
@@ -252,16 +258,59 @@ def _csr_window_bounds(sorted_codes, cells, dims: int, bits: int):
     return lo, hi
 
 
+def _csr_self_bounds(sorted_codes, sorted_cells, dims: int, bits: int):
+    """The self-join's window bounds: :func:`_csr_window_bounds` of the
+    corpus against itself, bit for bit, by one merge instead of 2·W binary
+    searches (DESIGN.md §3.2).
+
+    All W·n window codes (key ``2·code``) and the n corpus codes (key
+    ``2·code + 1``; codes are below 2^30, so keys fit int32) are sorted
+    together. A window key then precedes the corpus run of its own code:
+    the corpus keys before it count ``left``, the corpus keys up to the end
+    of its code's group count ``right``, and the cell is occupied iff
+    ``right > left``. A second sort, on the origin index, returns the
+    per-window bounds to (W, n) order for the min/max over each point's
+    window. No binary search: two sorts and two scans of W·n + n keys.
+    """
+    n = sorted_codes.shape[0]
+    wcodes = _csr_window_codes(sorted_cells, dims, bits)
+    nw = wcodes.size
+    keys = jnp.concatenate([wcodes.reshape(-1) * 2, sorted_codes * 2 + 1])
+    src = jnp.arange(nw + n, dtype=jnp.int32)
+    keys, src = jax.lax.sort((keys, src), num_keys=1, is_stable=False)
+    in_corpus = keys & 1
+    seen = jnp.cumsum(in_corpus)          # corpus keys up to here
+    code = keys >> 1
+    group_end = jnp.concatenate([code[1:] != code[:-1],
+                                 jnp.ones((1,), bool)])
+    # corpus keys up to the end of this key's code group: seen at the first
+    # group end at or after here (seen never decreases)
+    right = jax.lax.cummin(jnp.where(group_end, seen, n), reverse=True)
+    left = seen - in_corpus
+    occupied = right > left
+    lo_w = jnp.where(occupied, left, n)
+    hi_w = jnp.where(occupied, right, 0)
+    # back to (W, n) order: a second sort, keyed on the origin index, runs
+    # faster on the chip than a scatter of the same permutation
+    _, lo_w, hi_w = jax.lax.sort((src, lo_w, hi_w), num_keys=1,
+                                 is_stable=False)
+    return (lo_w[:nw].reshape(wcodes.shape).min(axis=0),
+            hi_w[:nw].reshape(wcodes.shape).max(axis=0))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("side", "origin", "dims", "bits"))
 def _csr_layout(points, side: float, origin: tuple, dims: int, bits: int):
-    """Shared sort-by-cell pass: identical arithmetic runs at plan time
-    (host) and build time (device), so the plan's slab capacity is valid for
-    the build — the CSR analogue of plan_grid's exactness contract."""
+    """Shared sort-by-cell pass: the plan and the build run this one
+    program, so the plan's slab capacity is valid for the build — the CSR
+    analogue of plan_grid's exactness contract — and a build compiles it
+    once per shape, at plan time."""
     from ..kernels import ref as _kref
     cells = csr_cells(points, side, origin, dims, bits)
     codes = _kref.morton_encode_ref(cells, dims=dims)
     order = jnp.argsort(codes).astype(jnp.int32)
     sorted_codes = codes[order]
-    lo, hi = _csr_window_bounds(sorted_codes, cells[order], dims, bits)
+    lo, hi = _csr_self_bounds(sorted_codes, cells[order], dims, bits)
     return order, points[order], lo, hi, sorted_codes
 
 
@@ -356,19 +405,21 @@ def compact_tiles(live):
     return jnp.where(idx < n_live, active, park), n_live
 
 
-def plan_csr_grid(points_np: np.ndarray, eps: float, *, dims: int = 3,
+def plan_csr_grid(points, eps: float, *, dims: int = 3,
                   chunk: int = 256, block_k: int = 512,
                   margin_blocks: int = 1) -> CSRGridSpec:
-    """Host-side planning pass for the CSR engine.
+    """Planning pass for the CSR engine.
 
     Runs the same sort-by-cell layout the device build runs and measures the
     worst per-tile slab extent, so the jitted build/sweep shapes are static
     yet sized by *actual* occupancy (one O(n log n) pass). ``side`` grows
     beyond ε only when the extent exceeds the Morton bit budget.
+    ``points`` is a host or a device array; planning on the array the build
+    will get lets the build reuse the layout program compiled here.
     """
-    n = len(points_np)
+    n = len(points)
     assert n >= 1, "plan_csr_grid needs at least one point"
-    pts = np.asarray(points_np, np.float32)
+    pts = np.asarray(points, np.float32)
     origin = tuple(float(v) for v in pts.min(axis=0))
     bits = 15 if dims == 2 else 10
     ext = float((pts.max(axis=0) - pts.min(axis=0))[:dims].max())
@@ -376,7 +427,8 @@ def plan_csr_grid(points_np: np.ndarray, eps: float, *, dims: int = 3,
     max_cells = (1 << bits) - 2
     if math.floor(ext / side) + 1 > max_cells:
         side = ext / (max_cells - 1) * (1 + 1e-5)
-    _, _, lo, hi, _ = _csr_layout(jnp.asarray(pts), side, origin, dims, bits)
+    _, _, lo, hi, _ = _csr_layout(jnp.asarray(points, jnp.float32), side,
+                                  origin, dims, bits)
     lo, hi = np.asarray(lo), np.asarray(hi)
     T = max(1, -(-n // chunk))
     pad_idx = np.minimum(np.arange(T * chunk), n - 1)
@@ -391,16 +443,21 @@ def plan_csr_grid(points_np: np.ndarray, eps: float, *, dims: int = 3,
 
 
 def build_csr_grid(points: jnp.ndarray, spec: CSRGridSpec) -> CSRGrid:
-    """Jitted CSR build: sort by cell code, derive per-tile slabs.
+    """CSR build: sort by cell code (the plan's own layout program, already
+    compiled for this shape when the spec was planned here), then derive
+    per-tile slabs in a second program.
 
     The ``overflow`` flag guards the plan/build parity contract (it fires
     only if device quantization disagrees with the host plan beyond the
     slab margin — callers should assert it is False once per build).
     """
-    n = points.shape[0]
-    order, spoints, lo, hi, codes = _csr_layout(points, spec.side,
-                                                spec.origin, spec.dims,
-                                                spec.bits)
+    return _csr_pack(*_csr_layout(points, spec.side, spec.origin, spec.dims,
+                                  spec.bits), spec=spec)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _csr_pack(order, spoints, lo, hi, codes, spec: CSRGridSpec) -> CSRGrid:
+    n = order.shape[0]
     starts, nblk, overflow = tile_slabs(
         lo, hi, n, n_tiles=spec.n_tiles, chunk=spec.chunk,
         block_k=spec.block_k, slab=spec.slab, n_cand=spec.n_cand)

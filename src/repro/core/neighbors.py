@@ -429,9 +429,9 @@ def _build_csr(points, eps, *, backend=None, chunk=2048, dims=None,
             dims = infer_dims(pts_np)
         sp.set_metadata(dims=dims)
         if spec is None:
-            spec = grid_mod.plan_csr_grid(pts_np, float(eps), dims=dims)
+            spec = grid_mod.plan_csr_grid(points, float(eps), dims=dims)
     with obs.span("engine.layout", slab=spec.slab):
-        g = build_csr_grid_jit(points, spec)
+        g = grid_mod.build_csr_grid(points, spec)
         overflowed = bool(g.overflow)
     if overflowed:
         raise ValueError(
@@ -489,8 +489,6 @@ engines.register_engine(
 
 
 build_grid_jit = jax.jit(grid_mod.build_grid, static_argnames=("spec",))
-build_csr_grid_jit = jax.jit(grid_mod.build_csr_grid,
-                             static_argnames=("spec",))
 neighbor_buckets_jit = jax.jit(grid_mod.neighbor_buckets,
                                static_argnames=("spec",))
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.grid import _csr_layout
 from repro.kernels.bvh_sweep import bvh_batch_sweep
 from repro.kernels.cross_sweep import cross_sweep
 from repro.kernels.csr_sweep import csr_sweep, csr_sweep_counts
@@ -108,3 +109,14 @@ def test_bvh_batch_sweep_compiles(one_chip, dims, prune_payload):
 def test_morton_encode_compiles(one_chip, dims):
     n = PLANS["taxi2d-1000000"][2]
     _compile(morton_encode, one_chip, ((3, n), jnp.int32), dims=dims)
+
+
+def test_csr_build_compiles_without_a_bisect(one_chip):
+    # the build's layout program, lowered for the chip, ranks its window
+    # codes by one merge (grid._csr_self_bounds); the binary search it
+    # replaced lowers to a while loop per window offset and side, so the
+    # lowered text shows a return to it without the slow sort compile
+    pts = jax.ShapeDtypeStruct((1000, 3), jnp.float32, sharding=one_chip)
+    lowered = _csr_layout.lower(pts, side=0.05, origin=(0.0, 0.0, 0.0),
+                                dims=3, bits=10)
+    assert "stablehlo.while" not in lowered.as_text()
