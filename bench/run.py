@@ -24,13 +24,20 @@ a run:
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the JAX profiler and the metrics are
 its per-layer metrics, each read by ``metrics/<name>.py`` from the trace
-(``trace.py``) and the harness's counters.
+(``trace.py``: device operations, the harness's ``bench.`` spans and, in
+``trace.program``, the program's ``repro.`` spans with their args) and the
+harness's counters.
 
 ``--rehearse`` runs the cell on the CPU with interpret-mode kernels at the
 configuration's and traffic's ``rehearsal`` sizes; its numbers are not
 device numbers, and only there may host events stand in for the device's
-in a trace. ``--control`` puts the reference, computed on bfloat16
-coordinates, in the program's place; its ``correct`` must come out false.
+in a trace. A cell on ``chips`` > 1 rehearses on as many virtual CPU
+devices: the run adds ``--xla_force_host_platform_device_count=<chips>``
+to ``XLA_FLAGS`` (unless a count is set there already) before JAX starts,
+so it rehearses in a fresh process; where JAX is already running with
+fewer devices it exits 2. ``--control`` puts the reference, computed on
+bfloat16 coordinates, in the program's place; its ``correct`` must come
+out false.
 """
 import time
 
@@ -58,6 +65,7 @@ if str(ROOT / "src") not in sys.path:
 
 EXIT_USAGE = 2
 EXIT_NO_DEVICE = 3
+DEVICE_COUNT_FLAG = "xla_force_host_platform_device_count"
 
 
 def _load_json(path: pathlib.Path) -> dict:
@@ -181,9 +189,14 @@ def main(argv=None, t_start: float = T_START) -> int:
     except (KeyError, FileNotFoundError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return EXIT_USAGE
+    chips = cell.workload["chips"]
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         os.environ.setdefault("REPRO_KERNEL_BACKEND", "interpret")
+        flags = os.environ.get("XLA_FLAGS", "")
+        if chips > 1 and DEVICE_COUNT_FLAG not in flags:
+            os.environ["XLA_FLAGS"] = \
+                f"{flags} --{DEVICE_COUNT_FLAG}={chips}".strip()
     elif os.environ.get("REPRO_KERNEL_BACKEND"):
         print("bench: REPRO_KERNEL_BACKEND is set "
               f"({os.environ['REPRO_KERNEL_BACKEND']!r}); the benchmark "
@@ -196,11 +209,15 @@ def main(argv=None, t_start: float = T_START) -> int:
 
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind
-    chips = cell.workload["chips"]
     if platform != "tpu" and not args.rehearse:
         print(f"bench: no TPU (JAX found {platform}); nothing run",
               file=sys.stderr)
         return EXIT_NO_DEVICE
+    if len(devices) < chips and args.rehearse:
+        print(f"bench: JAX is already running with {len(devices)} "
+              f"device(s); a {chips}-chip cell rehearses in a fresh process",
+              file=sys.stderr)
+        return EXIT_USAGE
     if len(devices) < chips:
         print(f"bench: the cell needs {chips} chips, JAX found "
               f"{len(devices)}", file=sys.stderr)

@@ -2,9 +2,13 @@
 
 The harness records one run's window with ``jax.profiler`` and marks its own
 host spans with ``jax.profiler.TraceAnnotation`` names that start with
-``SPAN_PREFIX``. From the trace this module takes:
+``SPAN_PREFIX``; the program marks its own with names that start with
+``PROGRAM_PREFIX`` (``repro/obs.py``), their args stats on the trace event.
+From the trace this module takes:
 
 * the window: the span named ``SPAN_PREFIX + "window"``;
+* the harness's spans and, apart (``Trace.program``), the program's spans
+  that overlap the window, each with its stats as ``args``;
 * device operations: the events of each device plane's op line, clipped to
   the window. A device plane is one whose name starts with ``/device:``.
   A trace without such a line is refused (``NoDeviceOps``), except in a
@@ -21,8 +25,8 @@ host spans with ``jax.profiler.TraceAnnotation`` names that start with
   that of the operations nested in it on the same device (a ``while``
   holds its body's operations);
 * idle gaps: the intervals inside the window in which no operation ran on a
-  device, each attributed to the innermost harness span that covers its
-  midpoint (what the host was doing).
+  device, each attributed to the innermost span of either kind that covers
+  its midpoint (what the host was doing).
 """
 from __future__ import annotations
 
@@ -33,6 +37,7 @@ import os
 import re
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 WINDOW = SPAN_PREFIX + "window"
 OP_LINES = ("XLA Ops",)
 
@@ -64,6 +69,12 @@ class Event:
     @property
     def kernel(self) -> str:
         return kernel_name(self.name)
+
+
+@dataclasses.dataclass(frozen=True)
+class Span(Event):
+    """A program span: a host event with its stats as ``args``."""
+    args: dict
 
 
 def merge(intervals) -> list:
@@ -107,25 +118,31 @@ def _event(ev) -> Event:
     return Event(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
 
 
-class Trace:
-    """One traced window: device operations per device and host spans."""
+def _overlaps(ev, start_ns: float, end_ns: float) -> bool:
+    return ev.end_ns > start_ns and ev.start_ns < end_ns
 
-    def __init__(self, devices: list, spans: list):
+
+class Trace:
+    """One traced window: device operations per device, the harness's host
+    spans and the program's."""
+
+    def __init__(self, devices: list, spans: list, program: list = ()):
         wins = [s for s in spans if s.name == WINDOW]
         if not wins:
             raise ValueError(f"trace has no {WINDOW!r} span")
         w = max(wins, key=lambda s: s.dur_ns)
         self.start_ns, self.end_ns = w.start_ns, w.end_ns
         self.spans = [s for s in spans
-                      if s.end_ns > self.start_ns
-                      and s.start_ns < self.end_ns]
+                      if _overlaps(s, self.start_ns, self.end_ns)]
+        self.program = sorted(
+            (s for s in program if _overlaps(s, self.start_ns, self.end_ns)),
+            key=lambda s: s.start_ns)
         self.devices = []
         for ops in devices:
             clipped = [dataclasses.replace(
                 o, start_ns=max(o.start_ns, self.start_ns),
                 end_ns=min(o.end_ns, self.end_ns))
-                for o in ops
-                if o.end_ns > self.start_ns and o.start_ns < self.end_ns]
+                for o in ops if _overlaps(o, self.start_ns, self.end_ns)]
             self.devices.append(clipped)
         self.busy = [merge((o.start_ns, o.end_ns) for o in ops)
                      for ops in self.devices]
@@ -135,7 +152,7 @@ class Trace:
         """The trace of ``planes``. Without a device op line it raises
         ``NoDeviceOps``, unless ``host_ops`` lets host events stand in."""
         planes = list(planes)  # ProfileData gives a one-pass iterator
-        devices, spans = [], []
+        devices, spans, program = [], [], []
         hosts = [p for p in planes if p.name.startswith("/host:")]
         for plane in planes:
             if plane.name.startswith("/device:"):
@@ -148,6 +165,10 @@ class Trace:
             for line in plane.lines:
                 spans += [_event(ev) for ev in line.events
                           if ev.name.startswith(SPAN_PREFIX)]
+                program += [Span(ev.name, ev.start_ns,
+                                 ev.start_ns + ev.duration_ns, dict(ev.stats))
+                            for ev in line.events
+                            if ev.name.startswith(PROGRAM_PREFIX)]
         if not devices and not host_ops:
             names = [p.name for p in planes]
             raise NoDeviceOps(f"no {' or '.join(OP_LINES)!r} line on a "
@@ -157,7 +178,7 @@ class Trace:
                    for ev in line.events
                    if any(k == "hlo_op" for k, _ in ev.stats)]
             devices = [ops] if ops else []
-        return cls(devices, spans)
+        return cls(devices, spans, program)
 
     @classmethod
     def load(cls, log_dir: str, host_ops: bool = False) -> "Trace":
@@ -204,6 +225,19 @@ class Trace:
     def spans_named(self, name: str) -> list:
         return [s for s in self.spans if s.name == SPAN_PREFIX + name]
 
+    def program_named(self, name: str) -> list:
+        """The program's spans named ``PROGRAM_PREFIX + name``."""
+        return [s for s in self.program if s.name == PROGRAM_PREFIX + name]
+
+    def per_request(self, name: str, value) -> dict:
+        """``{req: sum of value(span)}`` over the program's spans named
+        ``name``, by their ``req`` arg: a regrown request has two runs."""
+        out: dict = {}
+        for s in self.program_named(name):
+            req = s.args.get("req")
+            out[req] = out.get(req, 0.0) + value(s)
+        return out
+
     # --- breakdown -----------------------------------------------------------
 
     def top_ops(self, k: int = 10) -> list:
@@ -218,8 +252,9 @@ class Trace:
 
     def idle_gaps(self, k: int = 10) -> list:
         """``[[host span, seconds], ...]``: the longest intervals with no
-        device operation, over all devices, named by the innermost harness
-        span covering each gap's midpoint."""
+        device operation, over all devices, named by the innermost span,
+        the harness's (without its prefix) or the program's (in full),
+        covering each gap's midpoint."""
         gaps = []
         for m in self.busy:
             edges = [(self.start_ns, self.start_ns), *m,
@@ -229,7 +264,7 @@ class Trace:
         out = []
         for dur, s, e in sorted(gaps, reverse=True)[:k]:
             mid = (s + e) / 2
-            inner = [sp for sp in self.spans
+            inner = [sp for sp in (*self.spans, *self.program)
                      if sp.start_ns <= mid <= sp.end_ns]
             name = min(inner, key=lambda sp: sp.dur_ns).name \
                 if inner else "untraced"
