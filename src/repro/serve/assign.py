@@ -27,7 +27,6 @@ import time
 from typing import NamedTuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
@@ -75,7 +74,8 @@ def assign(snapshot: ClusterSnapshot, queries, *,
     reuse and latency/recompile telemetry across calls; without one an
     ephemeral scheduler still buckets (so one-off calls hit the same jit
     cache keys a loop would). ``req`` is the request id the call's spans
-    carry (``repro.obs``); a fresh one when not given.
+    carry (``repro.obs``); a fresh one when not given. The padded queries
+    go to the snapshot's own device (a sharded tier's leg, its shard's).
     """
     sched = scheduler or BucketScheduler(min_bucket=block_q)
     req = obs.next_req() if req is None else req
@@ -88,7 +88,7 @@ def assign(snapshot: ClusterSnapshot, queries, *,
                 f"bucket {q_pad.shape[0]} not a multiple of "
                 f"block_q={block_q}; set the scheduler's min_bucket to a "
                 "multiple of block_q")
-        q_dev = jnp.asarray(q_pad)
+        q_dev = jax.device_put(q_pad, snapshot.codes.sharding)
     spec = snapshot.spec
     eps2 = float(snapshot.eps) ** 2
 
@@ -106,7 +106,7 @@ def assign(snapshot: ClusterSnapshot, queries, *,
             fn = nb._csr_cross_query_fn(spec, eps2, backend, slab, block_q)
             counts, minroot, mind2, overflow = fn(
                 snapshot.codes, snapshot.cands, snapshot.croot_sorted,
-                q_dev, jnp.int32(nq))
+                q_dev, np.int32(nq))
             jax.block_until_ready(counts)
             overflowed = bool(overflow)
         if not overflowed and not faults.fire("serve.assign.overflow"):

@@ -80,6 +80,7 @@ import jax
 import numpy as np
 
 from .. import distributed as dist
+from .. import obs
 from . import faults
 from .assign import AssignResult, assign
 from .health import DOWN, HEALTHY, SUSPECT, HealthRegistry
@@ -356,18 +357,30 @@ class ShardedTier:
         single-snapshot ``assign`` on the unsplit corpus — the §15.3
         invariant the parity suite gates. With a shard quarantined and
         ``allow_partial`` on, the answer is the §16.3 *restriction*:
-        exactly the full merge minus the missing shard's contribution."""
-        q_np = validate_points(queries, name="queries")
-        ticket = self.admission.admit(len(q_np))
-        t0 = time.perf_counter()
-        try:
-            return self._assign_admitted(q_np)
-        finally:
-            self.admission.finish(ticket, time.perf_counter() - t0)
+        exactly the full merge minus the missing shard's contribution.
 
-    def _assign_admitted(self, q_np: np.ndarray) -> AssignResult:
-        mask = self.map.window_shards(q_np)
-        self.scheduler.note_route(mask.sum(axis=1))
+        One request id (``repro.obs``) is carried by the span
+        ``repro.tier.assign`` (``req``, ``nq``, ``shards`` routed to),
+        its ``tier.route``, one ``tier.leg`` per serve attempt and one
+        ``tier.merge`` per answered shard, and by every leg's
+        ``repro.serve.*`` spans."""
+        req = obs.next_req()
+        with obs.span("tier.assign", req=req) as sp:
+            q_np = validate_points(queries, name="queries")
+            sp.set_metadata(nq=len(q_np))
+            ticket = self.admission.admit(len(q_np))
+            t0 = time.perf_counter()
+            try:
+                with obs.span("tier.route", req=req):
+                    mask = self.map.window_shards(q_np)
+                    self.scheduler.note_route(mask.sum(axis=1))
+                sp.set_metadata(shards=int(mask.any(axis=0).sum()))
+                return self._assign_admitted(q_np, mask, req)
+            finally:
+                self.admission.finish(ticket, time.perf_counter() - t0)
+
+    def _assign_admitted(self, q_np: np.ndarray, mask: np.ndarray,
+                         req: int) -> AssignResult:
         nq = len(q_np)
         counts = np.zeros(nq, np.int32)
         merged = np.full(nq, INT64_MAX, np.int64)
@@ -380,7 +393,7 @@ class ShardedTier:
             idx = np.nonzero(mask[:, j])[0]
             if idx.size == 0:
                 continue
-            r, status = self._assign_leg(j, q_np[idx])
+            r, status = self._assign_leg(j, q_np[idx], req)
             shard_status[int(j)] = status
             staleness += status.staleness
             if r is None:
@@ -391,17 +404,18 @@ class ShardedTier:
                 # neighbors, never invents any (§16.3)
                 partial = True
                 continue
-            bucket += r.bucket
-            table = self.parts[j].label_table.astype(np.int64)
-            if table.size:
-                glab = np.where(r.labels >= 0,
-                                table[np.clip(r.labels, 0, None)],
-                                INT64_MAX)
-            else:
-                glab = np.full(idx.size, INT64_MAX, np.int64)
-            merged[idx] = np.minimum(merged[idx], glab)
-            counts[idx] += r.counts
-            dist_m[idx] = np.minimum(dist_m[idx], r.dist)
+            with obs.span("tier.merge", req=req, shard=j):
+                bucket += r.bucket
+                table = self.parts[j].label_table.astype(np.int64)
+                if table.size:
+                    glab = np.where(r.labels >= 0,
+                                    table[np.clip(r.labels, 0, None)],
+                                    INT64_MAX)
+                else:
+                    glab = np.full(idx.size, INT64_MAX, np.int64)
+                merged[idx] = np.minimum(merged[idx], glab)
+                counts[idx] += r.counts
+                dist_m[idx] = np.minimum(dist_m[idx], r.dist)
         if partial:
             self.scheduler.note_partial()
         labels = np.where(merged != INT64_MAX, merged, -1).astype(np.int32)
@@ -423,7 +437,7 @@ class ShardedTier:
             missing=missing, retries=retries, failovers=failovers,
             hedged=hedged)
 
-    def _assign_leg(self, j: int, q_sub: np.ndarray) -> tuple:
+    def _assign_leg(self, j: int, q_sub: np.ndarray, req: int) -> tuple:
         """One scatter leg behind the health registry (§16.2): serve the
         round-robin turn-holder among live replicas, hedge a suspect
         turn-holder to a second live copy (first result wins), absorb
@@ -447,7 +461,7 @@ class ShardedTier:
                 remaining.remove(alt)
                 hedged = True
                 r, winner, n_retry, err = self._hedged_pair(j, rep, alt,
-                                                            q_sub)
+                                                            q_sub, req)
                 retries += n_retry
                 if err is not None:
                     last_err = err
@@ -459,7 +473,7 @@ class ShardedTier:
                 failovers += 2
                 self.scheduler.note_failover()
                 continue
-            r, n_retry, err = self._try_target(j, rep, q_sub)
+            r, n_retry, err = self._try_target(j, rep, q_sub, req)
             retries += n_retry
             if err is not None:
                 last_err = err
@@ -478,7 +492,8 @@ class ShardedTier:
                                       retries=retries, failovers=failovers,
                                       hedged=hedged)
 
-    def _try_target(self, j: int, rep: int, q_sub: np.ndarray) -> tuple:
+    def _try_target(self, j: int, rep: int, q_sub: np.ndarray,
+                    req: int) -> tuple:
         """Bounded serve attempt(s) against one target; returns
         ``(result | None, retries_used, last_error)``. A ``faults.Kill``
         here is the *target's* death, not the router's — the failure-
@@ -489,14 +504,17 @@ class ShardedTier:
         path lets it propagate."""
         key = (j, rep)
         tag = target_tag(j, rep)
-        snaps = self._replica_snapshots(j)
+        snap = self._replica_snapshots(j)[rep]
         err = None
         for attempt in range(self.leg_retries + 1):
             t0 = time.perf_counter()
             try:
-                faults.fire("serve.shard.assign", tag)
-                r = assign(snaps[rep], q_sub, scheduler=self.scheduler,
-                           block_q=self.block_q, backend=self.backend)
+                with obs.span("tier.leg", req=req, shard=j, replica=rep,
+                              nq=len(q_sub)):
+                    faults.fire("serve.shard.assign", tag)
+                    r = assign(snap, q_sub, scheduler=self.scheduler,
+                               block_q=self.block_q, backend=self.backend,
+                               req=req)
             except faults.Kill:
                 self.health.force_down(key)
                 return None, attempt, AdmissionError(
@@ -521,7 +539,7 @@ class ShardedTier:
         return None, self.leg_retries, err
 
     def _hedged_pair(self, j: int, rep: int, alt: int,
-                     q_sub: np.ndarray) -> tuple:
+                     q_sub: np.ndarray, req: int) -> tuple:
         """§16.2 hedge: run the suspect turn-holder and a second live
         replica concurrently; the first successful result wins and the
         loser's work is discarded. Replicas share the shard's buffers,
@@ -531,7 +549,7 @@ class ShardedTier:
         finishes."""
         self.scheduler.note_hedge()
         ex = self._executor()
-        futs = {ex.submit(self._try_target, j, r, q_sub): r
+        futs = {ex.submit(self._try_target, j, r, q_sub, req): r
                 for r in (rep, alt)}
         result, winner, err, retries = None, -1, None, 0
         pending = set(futs)

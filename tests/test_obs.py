@@ -151,3 +151,67 @@ def test_tracing_changes_no_answer(session, tmp_path):
                                   np.asarray(traced_clusters.labels))
     np.testing.assert_array_equal(np.asarray(clusters.core),
                                   np.asarray(traced_clusters.core))
+
+
+@pytest.fixture(scope="module")
+def tier():
+    pts = synth.blobs(900, k=4, seed=1)
+    t = serve.ShardedTier.from_snapshot(serve.build_snapshot(pts, EPS, MINPTS),
+                                        n_shards=2)
+    assert t.n_shards == 2
+    t.assign(pts[::3])  # compile every shard's program outside the traces
+    yield t, pts
+    t.close()
+
+
+def test_tier_assign_spans_hold_route_legs_and_merges(tier, tmp_path):
+    t, pts = tier
+    q = pts[::3]
+    routed = t.map.window_shards(q)
+    want = [j for j in range(t.n_shards) if routed[:, j].any()]
+    assert len(want) == 2  # the request spans both shards
+    _, spans = _traced(tmp_path, lambda: t.assign(q))
+    top, = _named(spans, "tier.assign")
+    req = top.args["req"]
+    assert top.args["nq"] == len(q) and top.args["shards"] == 2
+    mine = [s for s in spans if s.args.get("req") == req]
+    # every span of the request shares req (a collection may add "gc")
+    assert len(mine) == len([s for s in spans if s.name != "gc"])
+    assert all(top.holds(s) for s in mine)
+    route, = _named(mine, "tier.route")
+    legs, merges = _named(mine, "tier.leg"), _named(mine, "tier.merge")
+    assert [s.args["shard"] for s in legs] == want
+    assert [s.args["shard"] for s in merges] == want
+    assert all(s.args["replica"] == 0 for s in legs)
+    assert [s.args["nq"] for s in legs] == [int(routed[:, j].sum())
+                                            for j in want]
+    # route, then each shard's leg and its merge, one after another
+    order = [route] + [s for pair in zip(legs, merges) for s in pair]
+    assert all(a.end <= b.start for a, b in zip(order, order[1:]))
+    for leg in legs:
+        inner = [s for s in mine if s.name.startswith("serve.")
+                 and leg.holds(s)]
+        assert sorted(s.name for s in inner) == [
+            "serve.finish", "serve.prepare", "serve.run"]
+        prep, = _named(inner, "serve.prepare")
+        assert prep.args["nq"] == leg.args["nq"]
+
+
+def test_tier_assign_ids_differ_between_requests(tier, tmp_path):
+    t, pts = tier
+    _, spans = _traced(tmp_path, lambda: (t.assign(pts[:40]),
+                                          t.assign(pts[-40:])))
+    reqs = [s.args["req"] for s in _named(spans, "tier.assign")]
+    assert len(reqs) == 2 and reqs[0] != reqs[1]
+    for s in spans:
+        assert s.name == "gc" or s.args.get("req") in reqs, s
+
+
+def test_tracing_changes_no_tier_answer(tier, tmp_path):
+    t, _ = tier
+    q = synth.blobs(300, k=4, seed=8)
+    plain = t.assign(q)
+    traced, _ = _traced(tmp_path, lambda: t.assign(q))
+    for f in ("labels", "counts", "dist"):
+        np.testing.assert_array_equal(getattr(plain, f), getattr(traced, f))
+    assert not traced.partial
