@@ -8,10 +8,10 @@ on the host's devices via ``distributed.shard_devices``) answer in
 shard-local label space, and the gather remaps + min-merges back to the
 global answer — bit-identical to the single-snapshot path (§15.3).
 
-**Query path.** ``assign`` computes each query's ε-dilated tier window,
-bisects the window cell codes against the global sorted codes, and
-scatters the batch's sub-sets to the 1–2 shards owning occupied runs
-(`ShardMap.window_shards`). Each shard runs the same bucketed
+**Query path.** ``assign`` looks each query's own tier cell up in the
+reach table the split built (the shards owning occupied runs in that
+cell's ε-dilated window) and scatters the batch's sub-sets to those 1–2
+shards (`ShardMap.window_shards`). Each shard runs the same bucketed
 ``cross_sweep`` program ``assign`` always ran — one shared
 :class:`BucketScheduler` fronts all shards (and their replicas) as the
 load balancer, and because trace keys carry the shard's plan, its
@@ -361,9 +361,9 @@ class ShardedTier:
 
         One request id (``repro.obs``) is carried by the span
         ``repro.tier.assign`` (``req``, ``nq``, ``shards`` routed to),
-        its ``tier.route``, one ``tier.leg`` per serve attempt and one
-        ``tier.merge`` per answered shard, and by every leg's
-        ``repro.serve.*`` spans."""
+        its ``tier.route`` (``hits``: queries routed to a shard), one
+        ``tier.leg`` per serve attempt and one ``tier.merge`` per
+        answered shard, and by every leg's ``repro.serve.*`` spans."""
         req = obs.next_req()
         with obs.span("tier.assign", req=req) as sp:
             q_np = validate_points(queries, name="queries")
@@ -371,9 +371,11 @@ class ShardedTier:
             ticket = self.admission.admit(len(q_np))
             t0 = time.perf_counter()
             try:
-                with obs.span("tier.route", req=req):
+                with obs.span("tier.route", req=req) as rsp:
                     mask = self.map.window_shards(q_np)
-                    self.scheduler.note_route(mask.sum(axis=1))
+                    per_q = mask.sum(axis=1)
+                    self.scheduler.note_route(per_q)
+                    rsp.set_metadata(hits=int(np.count_nonzero(per_q)))
                 sp.set_metadata(shards=int(mask.any(axis=0).sum()))
                 return self._assign_admitted(q_np, mask, req)
             finally:
@@ -752,6 +754,7 @@ class ShardedTier:
         p50, p99 = sch.latency_percentiles()
         return {
             "targets": targets,
+            "route_table_cells": int(self.map.reach_keys.size),
             "quarantined": [target_tag(q, None) for q in self.quarantined],
             "recovering": sorted(target_tag(q, None)
                                  for q in self._recovering),

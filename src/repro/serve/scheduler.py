@@ -113,8 +113,8 @@ class BucketScheduler:
         query, keyed by how many shards its ε-dilated window touched
         (DESIGN.md §15.2 — the locality claim is that this is almost
         always 1, occasionally 2, and 0 for far-away queries)."""
-        self.routed.update(int(v) for v in np.asarray(shards_per_query)
-                           .ravel())
+        hist = np.bincount(np.asarray(shards_per_query, np.int64).ravel())
+        self.routed.update({k: int(c) for k, c in enumerate(hist) if c})
 
     def note_regrow(self) -> None:
         """Record one slab overflow → regrow retry (assign or delta

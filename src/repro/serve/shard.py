@@ -7,8 +7,9 @@ positions, cut at count-balanced quantiles and then **snapped forward to
 the end of the enclosing code run** so one cell code never spans two
 shards. That snap is the routing exactness precondition: a query's
 ε-dilated window cell is either empty in the global corpus or its whole
-occupied run lies inside exactly one shard, so occupancy bisection
-against the global sorted codes names the shard directly (§15.2).
+occupied run lies inside exactly one shard, so the split can tabulate,
+once, which shards each cell's window reaches, and a query's route is
+one lookup of its own cell (§15.2).
 Snapping can collapse adjacent cuts (e.g. an all-duplicates corpus has
 one code), in which case the effective shard count is smaller than
 requested — never zero-point shards.
@@ -95,18 +96,94 @@ class ShardPart:
         return np.asarray(self.snapshot.points[:1], np.float32)
 
 
+def tier_cells(points_np, side: float, origin: tuple, dims: int,
+               bits: int) -> np.ndarray:
+    """(m, 3) int32 tier cells on the host: ``grid.csr_cells`` in numpy,
+    operation for operation in float32 (subtract the float32 origin,
+    multiply by ``float32(1 / side)``, floor, clip, zero z in 2-D), so a
+    query lands in the cell the device quantizes it to. The clip runs
+    before the integer cast: equal to XLA's saturating cast then clip for
+    every finite input, where numpy's own cast would wrap."""
+    p = np.asarray(points_np, np.float32)
+    with np.errstate(over="ignore"):  # past float32's range: ±inf, clipped
+        f = np.floor((p - np.asarray(origin, np.float32))
+                     * np.float32(1.0 / side))
+    c = np.clip(f, 0, (1 << bits) - 3).astype(np.int32)
+    if dims == 2:
+        c[:, 2] = 0
+    return c
+
+
+def morton_cells(codes: np.ndarray, dims: int) -> np.ndarray:
+    """(m, 3) int64 cells of (m,) Morton codes, the inverse of
+    ``ref.morton_encode_ref`` (z = 0 in 2-D)."""
+    k = np.asarray(codes, np.int64)
+    out = np.zeros((len(k), 3), np.int64)
+    if dims == 2:
+        out[:, 0], out[:, 1] = _compact2(k), _compact2(k >> 1)
+    else:
+        out[:, 0], out[:, 1], out[:, 2] = (_compact3(k), _compact3(k >> 1),
+                                           _compact3(k >> 2))
+    return out
+
+
+def _compact2(x):
+    x = x & 0x55555555
+    x = (x | (x >> 1)) & 0x33333333
+    x = (x | (x >> 2)) & 0x0F0F0F0F
+    x = (x | (x >> 4)) & 0x00FF00FF
+    return (x | (x >> 8)) & 0x7FFF
+
+
+def _compact3(x):
+    x = x & 0x09249249
+    x = (x | (x >> 2)) & 0x030C30C3
+    x = (x | (x >> 4)) & 0x0300F00F
+    x = (x | (x >> 8)) & 0x030000FF
+    return (x | (x >> 16)) & 0x3FF
+
+
+def _reach_table(codes: np.ndarray, pos_cuts: np.ndarray, dims: int,
+                 bits: int) -> tuple:
+    """Which shards each query cell's window reaches (§15.2).
+
+    A query in cell ``q`` can touch an occupied cell ``o`` only if
+    ``o = q + off`` for a window offset ``off``, so every occupied ``o``
+    adds its shard to the keys ``q = o - off`` that are real cells
+    (``[0, 2^bits - 3]`` on every axis). That is exact against the
+    clipped window the engine sweeps: a neighbour clipped to 0 is the
+    query cell's own row, one clipped to ``2^bits - 2`` is never occupied.
+    Returns the sorted unique keys (T,) int32 and a (T, K) bool reach;
+    T is at most 9 (2-D) or 27 (3-D) times the occupied cells.
+    """
+    occ, first = np.unique(codes, return_index=True)
+    # an occupied run never straddles a cut: its start position names
+    # the one shard holding it
+    sid = np.searchsorted(pos_cuts, first, side="right") - 1
+    offs = _window_offsets(dims).astype(np.int64)
+    q = morton_cells(occ, dims)[None, :, :] - offs[:, None, :]
+    real = ((q >= 0) & (q <= (1 << bits) - 3)).all(axis=2)
+    keys, inv = np.unique(kref.morton_encode_ref(q[real], dims=dims),
+                          return_inverse=True)
+    reach = np.zeros((len(keys), len(pos_cuts) - 1), bool)
+    reach[inv, np.broadcast_to(sid, real.shape)[real]] = True
+    return keys, reach
+
+
 @dataclasses.dataclass(frozen=True)
 class ShardMap:
-    """Routing structure: tier quantization + snapped cuts (§15.2).
+    """Routing structure: tier quantization, snapped cuts and the reach
+    table built from them (§15.2).
 
-    Owns no shard data — only the global sorted code array and the cut
-    positions/codes. Both routing questions reduce to ``searchsorted``:
+    Owns no shard data. Both routing questions reduce to one
+    ``searchsorted`` of each point's own tier cell code:
 
-    * **ingest** (``owner_of``): a point's tier code against the inner
-      cut codes — cut ranges partition the whole code space, so every
-      point has exactly one owning shard;
-    * **query** (``window_shards``): each of the query's 9/27 ε-dilated
-      window cell codes against the global sorted codes — an *occupied*
+    * **ingest** (``owner_of``): against the inner cut codes — cut ranges
+      partition the whole code space, so every point has exactly one
+      owning shard;
+    * **query** (``window_shards``): against the reach table's keys,
+      every cell whose ε-dilated window holds an occupied run, each with
+      the shards owning those runs (:func:`_reach_table`) — an occupied
       run lies wholly inside one shard (cuts are snapped to code
       boundaries), and only shards owning occupied window runs can hold
       an ε-neighbor, so the routed set is exact, typically 1–2 shards.
@@ -115,58 +192,42 @@ class ShardMap:
     origin: tuple
     dims: int
     bits: int
-    codes: np.ndarray       # (n,) int64 global Morton-sorted tier codes
     pos_cuts: np.ndarray    # (K+1,) int64 cut positions in sorted order
     cut_codes: np.ndarray   # (K+1,) int64: shard j owns [cut[j], cut[j+1])
+    reach_keys: np.ndarray  # (T,) int32 sorted codes of reaching cells
+    reach: np.ndarray       # (T, K) bool: key t's window holds shard j
 
     @property
     def n_shards(self) -> int:
         return len(self.pos_cuts) - 1
 
-    def _cells(self, points_np: np.ndarray) -> np.ndarray:
-        pts = jnp.asarray(np.asarray(points_np, np.float32))
-        return np.asarray(grid_mod.csr_cells(pts, self.side, self.origin,
-                                             self.dims, self.bits))
-
-    def _codes_of(self, cells_np: np.ndarray) -> np.ndarray:
-        codes = kref.morton_encode_ref(jnp.asarray(cells_np),
-                                       dims=self.dims)
-        return np.asarray(codes).astype(np.int64)
+    def _codes(self, points_np) -> np.ndarray:
+        # the kernels' reference encoder, on host arrays: numpy in and out
+        cells = tier_cells(points_np, self.side, self.origin, self.dims,
+                           self.bits)
+        return kref.morton_encode_ref(cells, dims=self.dims)
 
     def owner_of(self, points_np) -> np.ndarray:
         """(m,) int32 owning shard per point — the ingest route."""
-        codes = self._codes_of(self._cells(points_np))
-        return np.searchsorted(self.cut_codes[1:-1], codes,
+        return np.searchsorted(self.cut_codes[1:-1], self._codes(points_np),
                                side="right").astype(np.int32)
 
     def window_shards(self, points_np) -> np.ndarray:
         """(m, K) bool: shard j may hold an ε-neighbor of point i.
 
-        Mirrors ``grid._csr_window_bounds``'s cell enumeration exactly
-        (±1 per axis around the clipped tier cell, neighbors clipped to
-        the engine's cap): every corpus point within ε of a query sits
-        in one of these window cells — tier side ≥ ε, the same argument
-        that makes the engine's window sweep exact — so a shard outside
-        this mask cannot contribute a count, a minroot, or a mind2.
+        The window is ``grid._csr_window_bounds``'s cell enumeration (±1
+        per axis around the clipped tier cell, neighbors clipped to the
+        engine's cap): every corpus point within ε of a query sits in one
+        of these window cells — tier side ≥ ε, the same argument that
+        makes the engine's window sweep exact — so a shard outside this
+        mask cannot contribute a count, a minroot, or a mind2. A query
+        whose cell is not in the table routes nowhere.
         """
-        cells = self._cells(points_np)
-        m = len(cells)
-        offs = _window_offsets(self.dims)
-        cap = (1 << self.bits) - 2
-        nbc = np.clip(cells[None, :, :] + offs[:, None, :], 0, cap)
-        if self.dims == 2:
-            nbc[:, :, 2] = 0
-        codes = self._codes_of(nbc.reshape(-1, 3)).reshape(len(offs), m)
-        left = np.searchsorted(self.codes, codes, side="left")
-        right = np.searchsorted(self.codes, codes, side="right")
-        occ = right > left
-        # an occupied run never straddles a cut: its start position names
-        # the one shard holding it
-        sid = np.searchsorted(self.pos_cuts, left, side="right") - 1
-        mask = np.zeros((m, self.n_shards), bool)
-        oi, oj = np.nonzero(occ)
-        mask[oj, sid[oi, oj]] = True
-        return mask
+        codes = self._codes(points_np)
+        i = np.minimum(np.searchsorted(self.reach_keys, codes),
+                       len(self.reach_keys) - 1)
+        hit = self.reach_keys[i] == codes
+        return self.reach[i] & hit[:, None]
 
 
 def _build_part(shard_id: int, pts: np.ndarray, labels_global: np.ndarray,
@@ -250,7 +311,8 @@ def split_snapshot(snapshot: ClusterSnapshot,
             j, pts_g[rows], labels_g[rows], core_g[rows], counts_g[rows],
             rows, int(cut_codes[j]), int(cut_codes[j + 1]), spec,
             float(snapshot.eps), int(snapshot.min_pts), snapshot.engine))
+    reach_keys, reach = _reach_table(codes, pos_cuts, spec.dims, spec.bits)
     smap = ShardMap(side=spec.side, origin=tuple(spec.origin),
-                    dims=spec.dims, bits=spec.bits, codes=codes,
-                    pos_cuts=pos_cuts, cut_codes=cut_codes)
+                    dims=spec.dims, bits=spec.bits, pos_cuts=pos_cuts,
+                    cut_codes=cut_codes, reach_keys=reach_keys, reach=reach)
     return smap, parts

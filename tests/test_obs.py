@@ -8,11 +8,13 @@ import os
 from typing import NamedTuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
 from repro import obs, serve
+from repro.core import grid
 from repro.core.dbscan import dbscan
 from repro.data import synth
 from repro.serve import faults, snapshot
@@ -164,9 +166,21 @@ def tier():
     t.close()
 
 
+def _window_hits(t, pts, q):
+    """Queries with a corpus point in their ±1-cell window at the tier's
+    quantization, counted from the device's cells by brute force."""
+    smap = t.map
+
+    def cells(x):
+        return np.asarray(grid.csr_cells(jnp.asarray(x), smap.side,
+                                         smap.origin, smap.dims, smap.bits))
+    near = np.abs(cells(q)[:, None, :] - cells(pts)[None, :, :]).max(-1) <= 1
+    return int(near.any(axis=1).sum())
+
+
 def test_tier_assign_spans_hold_route_legs_and_merges(tier, tmp_path):
     t, pts = tier
-    q = pts[::3]
+    q = np.concatenate([pts[::3], pts[:7] + np.float32(50)])
     routed = t.map.window_shards(q)
     want = [j for j in range(t.n_shards) if routed[:, j].any()]
     assert len(want) == 2  # the request spans both shards
@@ -179,6 +193,8 @@ def test_tier_assign_spans_hold_route_legs_and_merges(tier, tmp_path):
     assert len(mine) == len([s for s in spans if s.name != "gc"])
     assert all(top.holds(s) for s in mine)
     route, = _named(mine, "tier.route")
+    # the far queries route nowhere: hits counts the rest
+    assert route.args["hits"] == _window_hits(t, pts, q) == len(q) - 7
     legs, merges = _named(mine, "tier.leg"), _named(mine, "tier.merge")
     assert [s.args["shard"] for s in legs] == want
     assert [s.args["shard"] for s in merges] == want

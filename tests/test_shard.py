@@ -8,11 +8,16 @@ per-shard checkpoint namespaces isolate keep-K GC and watermark pins;
 delta-overflow sheds name the owning shard.
 """
 import os
+from collections import Counter
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import serve
+from repro.core import grid
+from repro.kernels import ref as kref
+from repro.serve import shard as shard_mod
 from repro.core.dbscan import dbscan
 from repro.data import synth
 from repro.distributed import checkpoint as ckpt
@@ -179,6 +184,194 @@ def test_split_partitions_canonical_corpus():
     for p in parts:
         assert (smap.owner_of(pts[p.orig_index]) == p.shard_id).all()
 
+
+
+# --- the route table against the window bisect it replaces ------------------
+
+_ROUTE = {}
+
+
+def _route_corpus(dims):
+    """Blobs, and a tight island far out along x, in the grid's top real
+    cell (``2^bits - 3``, the cell clipped queries land in); between them
+    lies empty space. Returns (points, blob points, snapshot)."""
+    if dims not in _ROUTE:
+        blobs = synth.blobs(700, k=4, dims=dims, seed=3)
+        top = (1 << (15 if dims == 2 else 10)) - 3
+        org = blobs.min(0)
+        island = org + np.random.default_rng(dims).uniform(0, 0.02, (40, 3))
+        island[:, 0] += (top + 0.2) * EPS
+        pts = np.concatenate([blobs, island]).astype(np.float32)
+        if dims == 2:
+            pts[:, 2] = 0
+        _ROUTE[dims] = (pts, blobs, serve.build_snapshot(pts, EPS, MINPTS))
+    return _ROUTE[dims]
+
+
+def _device_codes(cells, dims):
+    return np.asarray(kref.morton_encode_ref(
+        jnp.asarray(cells.reshape(-1, 3), jnp.int32),
+        dims=dims)).astype(np.int64).reshape(cells.shape[:-1])
+
+
+def _device_cells(smap, q):
+    return np.asarray(grid.csr_cells(jnp.asarray(q, jnp.float32), smap.side,
+                                     smap.origin, smap.dims, smap.bits))
+
+
+def _bisect_window_shards(smap, codes, q):
+    """The route as the split used to answer it: each of the query's 9/27
+    window cells (device cells, neighbours clipped to the engine's cap)
+    bisected against the global sorted codes; an occupied run's start
+    names its shard."""
+    offs = np.asarray([(dx, dy, dz) for dx in (-1, 0, 1)
+                       for dy in (-1, 0, 1)
+                       for dz in ((-1, 0, 1) if smap.dims == 3 else (0,))])
+    nbc = np.clip(_device_cells(smap, q)[None] + offs[:, None], 0,
+                  (1 << smap.bits) - 2)
+    if smap.dims == 2:
+        nbc[..., 2] = 0
+    wc = _device_codes(nbc, smap.dims)
+    left = np.searchsorted(codes, wc, side="left")
+    occ = np.searchsorted(codes, wc, side="right") > left
+    sid = np.searchsorted(smap.pos_cuts, left, side="right") - 1
+    mask = np.zeros((len(q), smap.n_shards), bool)
+    oi, oj = np.nonzero(occ)
+    mask[oj, sid[oi, oj]] = True
+    return mask
+
+
+def _route_queries(pts, blobs, snap, smap):
+    """Random queries over and past the blobs and the island, far into
+    empty space and clipped at both ends of the grid, points on and
+    within ε of every cut, and points at exact multiples of the tier side
+    from the origin with their float32 neighbours on either side."""
+    rng = np.random.default_rng(11)
+    island = pts[len(blobs):]
+    qs = [rng.uniform(lo - 3 * EPS, hi + 3 * EPS, (300, 3))
+          for lo, hi in ((blobs.min(0), blobs.max(0)),
+                         (island.min(0), island.max(0)))]
+    qs += [rng.uniform(-1e4, 1e4, (20, 3)),
+           np.asarray([[1e30, 1e30, 1e30], [-1e30, -1e30, -1e30],
+                       [1e30, island[0, 1], island[0, 2]]])]
+    order = np.asarray(snap.order)
+    steps = [0, 0.5, 0.99, 1.0, 1.01]
+    for cut in smap.pos_cuts[1:-1]:
+        b = np.asarray(snap.points)[order[cut]].astype(np.float64)
+        for axis in range(smap.dims):
+            for d in steps + [-v for v in steps[1:]]:
+                qs.append((b + np.eye(3)[axis] * d * EPS)[None])
+    org = np.asarray(smap.origin, np.float64)
+    k = np.floor((pts[::7] - org) / smap.side)
+    qs.append(org + (k + rng.integers(-1, 3, k.shape)) * smap.side)
+    q = np.concatenate(qs).astype(np.float32)
+    q = np.concatenate([q, np.nextafter(q, np.float32(np.inf)),
+                        np.nextafter(q, np.float32(-np.inf))])
+    if smap.dims == 2:
+        q[:, 2] = 0
+    return q
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4])
+@pytest.mark.parametrize("dims", [2, 3])
+def test_window_shards_equal_window_bisect(dims, k):
+    pts, blobs, snap = _route_corpus(dims)
+    smap, _ = serve.split_snapshot(snap, k)
+    assert smap.dims == dims and smap.n_shards == k
+    assert smap.side == EPS  # the island sits in the top real cell
+    q = _route_queries(pts, blobs, snap, smap)
+    got = smap.window_shards(q)
+    want = _bisect_window_shards(smap, np.asarray(snap.codes, np.int64), q)
+    np.testing.assert_array_equal(got, want)
+    # the draw reaches every case: unrouted queries, and queries routed
+    # to more than one shard when there is more than one
+    assert (~want.any(axis=1)).any()
+    assert (want.sum(axis=1) > 1).any() == (k > 1)
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4])
+@pytest.mark.parametrize("dims", [2, 3])
+def test_owner_of_equals_cut_bisect(dims, k):
+    pts, blobs, snap = _route_corpus(dims)
+    smap, _ = serve.split_snapshot(snap, k)
+    q = _route_queries(pts, blobs, snap, smap)
+    want = np.searchsorted(smap.cut_codes[1:-1],
+                           _device_codes(_device_cells(smap, q), smap.dims),
+                           side="right")
+    np.testing.assert_array_equal(smap.owner_of(q), want)
+    assert set(want.tolist()) == set(range(k))
+
+
+@pytest.mark.parametrize("plan", ["snapshot", "awkward"])
+@pytest.mark.parametrize("dims", [2, 3])
+def test_tier_cells_equal_csr_cells(dims, plan):
+    """The host cell rule is the device's bit for bit, on the default
+    backend: the route's exactness rests on it."""
+    _, _, snap = _route_corpus(dims)
+    smap, _ = serve.split_snapshot(snap, 2)
+    side, origin = smap.side, smap.origin
+    if plan == "awkward":
+        side, origin = 0.0137, (-0.3331, 0.2719, -1.1e-3)
+    rng = np.random.default_rng(dims)
+    org = np.asarray(origin, np.float64)
+    k = rng.integers(-3, 1 << 11, (2000, 3)).astype(np.float64)
+    edge = (org + k * side).astype(np.float32)
+    q = np.concatenate([
+        rng.uniform(-2.0, 3.0, (2000, 3)).astype(np.float32), edge,
+        np.nextafter(edge, np.float32(np.inf)),
+        np.nextafter(edge, np.float32(-np.inf)),
+        np.asarray([[1e30, -1e30, 3e38], [-3e38, 0, 1e9]], np.float32)])
+    bits = smap.bits
+    want = np.asarray(grid.csr_cells(jnp.asarray(q), side, origin, dims,
+                                     bits))
+    np.testing.assert_array_equal(
+        shard_mod.tier_cells(q, side, origin, dims, bits), want)
+
+
+@pytest.mark.parametrize("dims", [2, 3])
+def test_morton_cells_invert_the_encoder(dims):
+    bits = 15 if dims == 2 else 10
+    rng = np.random.default_rng(dims)
+    cells = rng.integers(0, (1 << bits) - 1, (5000, 3))
+    cells[:4] = [[0, 0, 0], [(1 << bits) - 2] * 3, [(1 << bits) - 3, 0, 1],
+                 [1, (1 << bits) - 2, 0]]
+    if dims == 2:
+        cells[:, 2] = 0
+    codes = _device_codes(cells, dims)
+    # the route encodes host cells with the same reference, on numpy
+    np.testing.assert_array_equal(kref.morton_encode_ref(cells, dims=dims),
+                                  codes)
+    np.testing.assert_array_equal(shard_mod.morton_cells(codes, dims), cells)
+
+def test_resplit_rebuilds_the_route_table():
+    """Every split brings its own table, the compaction's re-split too,
+    and the tier reports its size."""
+    pts, _, snap = _route_corpus(2)
+    tier = serve.ShardedTier.from_snapshot(snap, n_shards=3)
+    try:
+        first = tier.map
+        assert tier.health_report()["route_table_cells"] \
+            == first.reach_keys.size > 0
+        extra = pts[:64] + np.float32(3 * EPS)
+        tier.ingest(extra)
+        tier.compact(force=True)
+        assert tier.map is not first
+        fresh, _ = serve.split_snapshot(
+            serve.build_snapshot(np.concatenate([pts, extra]), EPS, MINPTS),
+            3)
+        np.testing.assert_array_equal(tier.map.reach_keys, fresh.reach_keys)
+        np.testing.assert_array_equal(tier.map.reach, fresh.reach)
+        assert tier.health_report()["route_table_cells"] \
+            == fresh.reach_keys.size
+    finally:
+        tier.close()
+
+def test_route_notes_the_same_fanout_histogram():
+    sched = serve.BucketScheduler()
+    per_q = np.asarray([0, 1, 1, 4, 2, 1, 0, 4])
+    sched.note_route(per_q)
+    sched.note_route(per_q[:0])
+    assert sched.routed == Counter(int(v) for v in per_q)
 
 def test_overflow_shed_names_owning_shard():
     pts = synth.load("skewed2d", 600, seed=4)
