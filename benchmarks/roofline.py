@@ -1,82 +1,12 @@
-"""§Roofline report: renders the dry-run JSONs into the per-(arch × shape)
-three-term table (single-pod, per spec) + per-cell bottleneck commentary.
+"""BVH wavefront level-kernel roofline model (DESIGN.md §13).
 
-Run after ``python -m repro.launch.dryrun``:
-    PYTHONPATH=src python -m benchmarks.roofline [--dir results/dryrun]
-        [--mesh single] [--markdown]
+``bvh_level_report`` turns the calibrated per-level frontier sizes into
+per-level bytes, FLOPs and launch counts; ``figures.roofline`` prints it
+(``PYTHONPATH=src python -m benchmarks.run --only roofline``).
 """
 from __future__ import annotations
 
-import argparse
-import glob
-import json
-import os
 
-
-MOVE_HINTS = {
-    "compute_s": "raise arithmetic efficiency: bf16 everywhere, fuse "
-                 "elementwise chains, cut causal-mask waste",
-    "memory_s": "cut HBM traffic: larger fusion regions, lower-precision "
-                "activations/cache, avoid re-read of stacked params",
-    "collective_s": "reshard to shrink all-gathers (FSDP prefetch once per "
-                    "step), overlap collectives with layer compute, "
-                    "compress gradients",
-}
-
-
-def load(dir_: str, mesh: str):
-    recs = []
-    for f in sorted(glob.glob(os.path.join(dir_, f"*__{mesh}.json"))):
-        recs.append(json.load(open(f)))
-    return recs
-
-
-def render(recs, markdown: bool = False):
-    sep = " | " if markdown else "  "
-    hdr = ["arch", "shape", "compute_s", "memory_s", "collective_s",
-           "bound", "MODEL/HLO", "roofline%"]
-    lines = []
-    if markdown:
-        lines.append("| " + " | ".join(hdr) + " |")
-        lines.append("|" + "---|" * len(hdr))
-    else:
-        lines.append(f"{hdr[0]:24s}{sep}{hdr[1]:12s}{sep}"
-                     + sep.join(f"{h:>12s}" for h in hdr[2:]))
-    for r in recs:
-        if r["status"] == "skipped":
-            row = [r["arch"], r["shape"], "-", "-", "-", "skipped",
-                   "-", "-"]
-        elif r["status"] != "ok":
-            row = [r["arch"], r["shape"], "-", "-", "-", "ERROR", "-", "-"]
-        else:
-            t = r["terms"]
-            row = [r["arch"], r["shape"], f"{t['compute_s']:.4f}",
-                   f"{t['memory_s']:.4f}", f"{t['collective_s']:.4f}",
-                   r["bottleneck"].replace("_s", ""),
-                   f"{1.0 / max(r.get('useful_flops_ratio', 1e-9), 1e-9):.2f}"
-                   if r.get("useful_flops_ratio") else "-",
-                   f"{100 * r.get('roofline_fraction', 0):.2f}"]
-        if markdown:
-            lines.append("| " + " | ".join(str(c) for c in row) + " |")
-        else:
-            lines.append(f"{row[0]:24s}{sep}{row[1]:12s}{sep}"
-                         + sep.join(f"{c:>12s}" for c in row[2:]))
-    return "\n".join(lines)
-
-
-def commentary(recs):
-    out = []
-    for r in recs:
-        if r["status"] != "ok":
-            continue
-        b = r["bottleneck"]
-        out.append(f"{r['arch']} × {r['shape']}: bound by {b}"
-                   f" — {MOVE_HINTS[b]}")
-    return "\n".join(out)
-
-
-# --- BVH wavefront level kernel (DESIGN.md §13) ---------------------------
-#
 # Static traffic/arithmetic model of one batched expand entry, used to turn
 # the measured per-level frontier sizes into a per-level roofline row. The
 # byte model charges what the level loop actually streams per (block, node)
@@ -133,25 +63,3 @@ def bvh_level_report(levels, *, batch: int, dims: int, tile: int,
     }
     return {"levels": rows, "total": total,
             "entry_bytes": eb, "entry_flops": ef}
-
-
-def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--dir", default="results/dryrun")
-    ap.add_argument("--mesh", default="single")
-    ap.add_argument("--markdown", action="store_true")
-    ap.add_argument("--hints", action="store_true")
-    args = ap.parse_args(argv)
-    recs = load(args.dir, args.mesh)
-    if not recs:
-        print("no dry-run records found — run "
-              "`PYTHONPATH=src python -m repro.launch.dryrun` first")
-        return
-    print(render(recs, markdown=args.markdown))
-    if args.hints:
-        print()
-        print(commentary(recs))
-
-
-if __name__ == "__main__":
-    main()

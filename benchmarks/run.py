@@ -4,10 +4,10 @@
                                             [--json [PATH]]
                                             [--check-regress [PATH]]
 
-Prints ``name,case,seconds,derived`` CSV (plus the roofline table when
-dry-run results exist). With ``--json`` the same rows are also written as
-``BENCH_sweep.json`` (per-case name/seconds/derived/engine), so the perf
-trajectory is machine-readable and diffable across PRs.
+Prints ``name,case,seconds,derived`` CSV. With ``--json`` the same rows
+are also written as ``BENCH_sweep.json`` (per-case
+name/seconds/derived/engine), so the perf trajectory is machine-readable
+and diffable across PRs.
 
 ``--check-regress`` compares the fresh run against a committed
 ``BENCH_sweep.json`` and exits nonzero on regression, so CI can gate on
@@ -179,11 +179,6 @@ def main(argv=None):
                        "rows": common.JSON_SINK}, f, indent=2)
         print(f"# wrote {len(common.JSON_SINK)} records to {args.json}",
               flush=True)
-
-    if os.path.isdir("results/dryrun") and not args.only:
-        print("\n# Roofline (single-pod, from dry-run):")
-        from . import roofline
-        roofline.main(["--dir", "results/dryrun", "--mesh", "single"])
 
     if baseline_rows is not None:
         problems = check_regress(

@@ -1,3 +1,2 @@
 """Dataset substrate: synthetic analogues of the paper's four evaluation
-datasets (``synth``) and the sharded token pipeline for the LM workloads
-(``pipeline``)."""
+datasets (``synth``) and the chunked point feeder (``pipeline.point_stream``)."""

@@ -1,50 +1,12 @@
 """Data pipelines.
 
-``token_batches`` — deterministic synthetic LM token stream (per-step PRNG
-key derived from (seed, step), so a restart regenerates the exact stream —
-the property the exact-resume checkpoint test relies on).
-
 ``point_stream`` — chunked point-cloud feeder for the clustering driver
 (reads generator-backed shards; a real deployment maps this to sharded
 parquet/TFRecord readers with per-host offsets).
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
-
-from ..configs.base import ArchConfig
-from ..models import model as M
-
-
-def token_batches(cfg: ArchConfig, batch: int, seq: int, seed: int = 0,
-                  start_step: int = 0):
-    """Learnable synthetic LM stream: a fixed (per-seed) permutation cycle
-    over a small token subset — next-token is a deterministic bigram map, so
-    the loss demonstrably falls well below the vocab entropy within tens of
-    steps. 5% noise keeps the floor non-zero."""
-    import jax.numpy as jnp
-    v = cfg.vocab
-    k_perm = jax.random.PRNGKey(seed + 7_919)
-    support = jax.random.choice(k_perm, v, (64,), replace=False)
-    cycle = jax.random.permutation(jax.random.fold_in(k_perm, 1), 64)
-    step = start_step
-    while True:
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        k1, k2 = jax.random.split(key)
-        phase = jax.random.randint(k1, (batch, 1), 0, 64)
-        pos = jnp.arange(seq + 1)[None, :]
-        idx = cycle[(phase + pos) % 64]
-        toks = support[idx]
-        noise = jax.random.bernoulli(k2, 0.05, toks.shape)
-        toks = jnp.where(noise, (toks + 1) % v, toks).astype(jnp.int32)
-        batch_d = {"tokens": toks[:, :seq], "labels": toks[:, 1:]}
-        extras = M.synth_batch(cfg, batch, seq, key)
-        for k in extras:
-            if k not in batch_d:
-                batch_d[k] = extras[k]
-        yield batch_d
-        step += 1
 
 
 def point_stream(name: str, total: int, chunk: int, seed: int = 0):

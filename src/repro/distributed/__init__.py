@@ -1,5 +1,6 @@
-"""Distributed runtime: sharded DBSCAN, checkpointing, elasticity,
-compressed collectives."""
+"""Distributed runtime: sharded DBSCAN over a device mesh
+(``dbscan_dist``), checkpointing (``checkpoint``) and the serving tier's
+shard placement (``shard_devices``)."""
 from __future__ import annotations
 
 

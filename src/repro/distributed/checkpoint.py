@@ -1,18 +1,14 @@
-"""Fault-tolerant checkpointing: atomic, keep-K, mesh-elastic.
+"""Fault-tolerant checkpointing: atomic, keep-K, pinned, namespaced.
 
-Design (1000+-node posture, DESIGN.md §4):
+Design (DESIGN.md §10.1, §14.3, §15.4):
   * atomic: write to ``step_XXXX.tmp`` then rename — a crash mid-write can
     never corrupt the restore point;
   * keep-K: bounded disk; the newest complete checkpoint wins on restore;
-  * host-agnostic payload: arrays are saved *unsharded* (npz of gathered
-    leaves) with the pytree structure, so a restart may resume on a
-    different device count / mesh shape — the loader reshards onto whatever
-    mesh the new job builds (elastic restart);
-  * metadata carries the step and a user dict (dataset position, RNG, mesh
-    shape) for exact-resume bookkeeping.
-
-For multi-host deployment the same format is written by host 0 of each data
-replica; this container is single-process so that reduces to one writer.
+  * pins: steps a live WAL watermark references survive keep-K GC;
+  * namespaces: each writer (e.g. a serving shard) gets its own step
+    sequence, GC and pins;
+  * arrays are saved as host npz leaves with the pytree structure, and
+    metadata carries the step and a user dict.
 """
 from __future__ import annotations
 
@@ -122,10 +118,8 @@ def latest_step(ckpt_dir: str, *,
 
 
 def restore(ckpt_dir: str, tree_like, *, step: Optional[int] = None,
-            shardings=None, namespace: Optional[str] = None):
-    """Restore into the structure of ``tree_like``; optionally place each
-    leaf with ``shardings`` (same pytree of NamedSharding) — this is where
-    elastic resharding onto a new mesh happens."""
+            namespace: Optional[str] = None):
+    """Restore host arrays into the structure of ``tree_like``."""
     ckpt_dir = namespace_dir(ckpt_dir, namespace)
     if step is None:
         step = latest_step(ckpt_dir)
@@ -135,9 +129,6 @@ def restore(ckpt_dir: str, tree_like, *, step: Optional[int] = None,
     with np.load(os.path.join(path, "arrays.npz")) as z:
         leaves_like, treedef = _flatten(tree_like)
         leaves = [z[f"leaf_{i}"] for i in range(len(leaves_like))]
-    if shardings is not None:
-        sh_leaves = jax.tree.leaves(shardings)
-        leaves = [jax.device_put(x, s) for x, s in zip(leaves, sh_leaves)]
     with open(os.path.join(path, "meta.json")) as f:
         meta = json.load(f)
     return jax.tree.unflatten(treedef, leaves), meta

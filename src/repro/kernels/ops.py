@@ -4,8 +4,8 @@ Backends:
   * ``kernel``    — compiled Pallas (Mosaic) — the TPU production path;
   * ``interpret`` — Pallas interpret mode — kernel-body semantics on CPU,
                     used for validation in this (CPU-only) container;
-  * ``ref``       — the pure-jnp oracle (``ref.py``) — the CPU execution and
-                    dry-run lowering path (no Mosaic backend on CPU).
+  * ``ref``       — the pure-jnp oracle (``ref.py``) — the CPU execution
+                    path (no Mosaic backend on CPU).
 
 Selection: explicit ``backend=`` argument, else ``$REPRO_KERNEL_BACKEND``,
 else ``kernel`` on TPU / ``ref`` otherwise. Wrappers own all padding so the

@@ -3,8 +3,7 @@
 These are the semantic ground truth: each Pallas kernel must match its oracle
 bit-for-bit on integer outputs and to float tolerance on float outputs, over
 shape/dtype sweeps (see tests/test_kernels.py). They are also the CPU
-execution path (the container has no Mosaic backend) and the path the
-multi-pod dry-run lowers.
+execution path (there is no Mosaic backend on CPU).
 
 Sweep payload convention (used by both DBSCAN stages, fused — see DESIGN.md):
   counts[i]   = |{ j : dist²(q_i, c_j) ≤ ε², c_j valid }|   (self included)

@@ -1,3 +1,3 @@
-"""Launchers: production mesh, multi-pod dry-run, training/serving/cluster
-drivers. ``dryrun.py`` must be the process entry point (it pins the XLA
-device count before any jax import)."""
+"""Launchers: the clustering CLI (``cluster``), JAX's persistent compile
+cache for the entry points (``compile_cache``) and device-mesh
+construction (``mesh``)."""

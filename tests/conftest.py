@@ -4,8 +4,8 @@ import sys
 
 import pytest
 
-# Tests must see exactly ONE device (the dry-run alone uses 512 placeholder
-# devices, set inside launch/dryrun.py before any jax import — never here).
+# Tests must see exactly ONE device: a test that needs more starts a fresh
+# subprocess with --xla_force_host_platform_device_count set before jax loads.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 

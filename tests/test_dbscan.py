@@ -19,13 +19,17 @@ CASES = [
 ]
 
 
+@pytest.mark.parametrize("hook_loop", ["device", "host", "frontier"])
 @pytest.mark.parametrize("engine", ["brute", "grid", "grid-hash", "bvh",
                                     "bvh-stack"])
 @pytest.mark.parametrize("name,pts,eps,minpts", CASES,
                          ids=[c[0] for c in CASES])
-def test_dbscan_equivalent_to_reference(engine, name, pts, eps, minpts):
+def test_dbscan_equivalent_to_reference(engine, name, pts, eps, minpts,
+                                        hook_loop):
+    # every stage-2 driver, including frontier's fall-back on engines
+    # without ``sweep_frontier``, must agree with Algorithm 1
     ref_labels, ref_core = reference_dbscan(pts, eps, minpts)
-    res = dbscan(pts, eps, minpts, engine=engine)
+    res = dbscan(pts, eps, minpts, engine=engine, hook_loop=hook_loop)
     assert np.array_equal(np.asarray(res.core), ref_core)
     assert L.equivalent(np.asarray(res.labels), ref_labels, ref_core,
                         points=pts, eps=eps)

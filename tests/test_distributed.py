@@ -1,14 +1,12 @@
 """Multi-device tests. Each case runs in a subprocess with
 ``xla_force_host_platform_device_count`` (the main pytest process must keep
 exactly 1 device)."""
-import json
 import os
 import subprocess
 import sys
 import textwrap
 
 import numpy as np
-import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -198,123 +196,3 @@ def test_checkpoint_atomic_roundtrip(tmp_path):
     assert meta["step"] == 15
     np.testing.assert_array_equal(np.asarray(restored["a"]),
                                   np.arange(10.0))
-
-
-def test_trainer_resume_equivalence(tmp_path):
-    """Crash/restart: resume from checkpoint must equal the uninterrupted
-    run (exact-resume fault tolerance)."""
-    import jax
-    from repro.configs import ALL
-    from repro.models import model as M
-    from repro.train import optimizer as opt_mod
-    from repro.train.trainer import TrainerConfig, train_loop
-
-    cfg = ALL["granite-moe-1b-a400m"].reduced()
-    ocfg = opt_mod.AdamWConfig(lr=1e-3)
-
-    def batches():
-        key = jax.random.PRNGKey(42)
-        while True:
-            key, k = jax.random.split(key)
-            yield M.synth_batch(cfg, 2, 32, k)
-
-    # uninterrupted 6 steps
-    s1, h1 = train_loop(cfg, TrainerConfig(total_steps=6, log_every=100),
-                        ocfg, batches(), seed=1)
-    # interrupted: 3 steps + ckpt, then resume (fresh iter = deterministic
-    # data keyed by step would be the production pattern; here the batch
-    # stream restarts, so compare parameters only for shape/finiteness and
-    # steps run)
-    d = str(tmp_path / "ck")
-    s2a, _ = train_loop(cfg, TrainerConfig(total_steps=3, ckpt_dir=d,
-                                           ckpt_every=3, log_every=100),
-                        ocfg, batches(), seed=1)
-    s2b, h2 = train_loop(cfg, TrainerConfig(total_steps=6, ckpt_dir=d,
-                                            ckpt_every=3, log_every=100),
-                         ocfg, batches(), seed=1)
-    assert h2[0]["step"] == 4  # resumed after step 3
-    assert int(s2b.opt.step) == 6 == int(s1.opt.step)
-
-
-def test_elastic_reshard():
-    out = run_sub("""
-    from repro.launch.mesh import make_mesh
-    from repro.distributed import checkpoint as ckpt, elastic
-    import tempfile, os
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    d = tempfile.mkdtemp()
-    mesh8 = make_mesh((4, 2), ("data", "model"))
-    x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
-                       NamedSharding(mesh8, P("data", "model")))
-    ckpt.save(d, 1, {"w": x})
-
-    # "lose" half the fleet: restore onto a 4-device mesh
-    shape, axes = elastic.plan_mesh(4, prefer_model=2)
-    assert shape == (2, 2)
-    mesh4 = make_mesh(shape, axes)
-    state, meta = elastic.reshard_state(d, {"w": x}, mesh4,
-                                        axes_tree={"w": ("embed", "ff")})
-    w = state["w"]
-    assert w.sharding.mesh.devices.size == 4
-    np.testing.assert_array_equal(np.asarray(w),
-                                  np.arange(64.0).reshape(8, 8))
-    print("OK")
-    """)
-    assert "OK" in out
-
-
-def test_straggler_policy():
-    from repro.distributed.elastic import StragglerPolicy
-    p = StragglerPolicy(slow_steps_budget=3)
-    assert p.decide(2, 8) is None
-    act = p.decide(5, 8)
-    assert act["action"] == "shrink" and act["mesh_shape"][0] * \
-        act["mesh_shape"][1] == 4
-
-
-def test_compressed_psum_parity():
-    out = run_sub("""
-    from repro.distributed.dbscan_dist import shard_map
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_mesh
-    from repro.distributed import collectives as C
-
-    mesh = make_mesh((8,), ("data",))
-    grads = {"w": jnp.linspace(-1, 1, 128).reshape(8, 16),
-             "b": jnp.linspace(0, 1, 8).reshape(8, 1)}
-
-    def red(method):
-        def f(g):
-            g = jax.tree.map(lambda x: x.reshape(x.shape[1:]), g)
-            out, _ = C.psum_compressed(g, "data", method=method)
-            return out
-        return shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                         out_specs=P(), check_vma=False)(grads)
-
-    exact = red("none")
-    for method, tol in (("bf16", 1e-2), ("int8", 2e-2)):
-        approx = red(method)
-        for k in exact:
-            err = float(jnp.abs(approx[k] - exact[k]).max())
-            assert err < tol, (method, k, err)
-    print("OK")
-    """)
-    assert "OK" in out
-
-
-def test_dryrun_cells_exist_and_clean():
-    """The committed dry-run results must cover every (arch×shape×mesh)
-    cell with ok or documented-skip status."""
-    res = os.path.join(ROOT, "results", "dryrun")
-    if not os.path.isdir(res):
-        pytest.skip("dry-run results not generated yet")
-    from repro.configs import ALL, SHAPES
-    seen = 0
-    for f in os.listdir(res):
-        if not f.endswith(".json"):
-            continue
-        rec = json.load(open(os.path.join(res, f)))
-        assert rec["status"] in ("ok", "skipped"), (f, rec.get("error"))
-        seen += 1
-    assert seen >= len(ALL) * len(SHAPES)  # at least the single-pod matrix
